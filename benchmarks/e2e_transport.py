@@ -59,9 +59,7 @@ def run_config(dims: int, records: int, bootstrap: str, log_dir: str,
             env=CPU_PLANE_ENV,
         )
         wait_for_broker(bootstrap)
-        # workers share the checkout-local compile cache via
-        # default_cache_dir(); SKYLINE_COMPILE_CACHE overrides it if the
-        # operator relocated the cache
+        # workers share one compile cache (enable_compile_cache)
         worker_env = dict(CPU_PLANE_ENV) if cpu else None
         stack.start(
             "worker",

@@ -34,14 +34,6 @@ import numpy as np
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")  # lint: allow-raw-env
-_flags = os.environ.get("XLA_FLAGS", "")  # lint: allow-raw-env
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=2"
-    ).strip()
-
-
 def _build(d: int):
     from skyline_tpu.distributed import ShardedEngine
     from skyline_tpu.stream import EngineConfig
@@ -222,4 +214,12 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # run alone, the A/B pins two virtual CPU chips; imported (bench.py)
+    # it runs on whatever devices the caller has
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")  # lint: allow-raw-env
+    _flags = os.environ.get("XLA_FLAGS", "")  # lint: allow-raw-env
+    if "xla_force_host_platform_device_count" not in _flags:
+        os.environ["XLA_FLAGS"] = (
+            _flags + " --xla_force_host_platform_device_count=2"
+        ).strip()
     raise SystemExit(main())

@@ -46,8 +46,6 @@ import numpy as np
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")  # lint: allow-raw-env
-
 _ROWS = 512  # published skyline rows (body ~8 KB/format at d=8)
 _DIMS = 8
 _PUBLISH_PERIOD_S = 0.1  # background republish cadence during timing
@@ -413,4 +411,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # run alone it is a host-side A/B; imported (bench.py) it runs on the
+    # caller's devices
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")  # lint: allow-raw-env
     main()

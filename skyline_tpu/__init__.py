@@ -24,64 +24,7 @@ Subpackage map (reference parity noted per SURVEY.md §2):
                   (python/metrics_collector.py; FlinkSkyline.java timing fields)
 - ``plots``     — figure tools (python/graph_*.py)
 - ``utils``     — config, padding/bucketing, checkpointing
-
-Import-time side effect: if ``JAX_PLATFORMS`` is set in the environment and
-the JAX backend is not yet initialized, importing this package re-applies the
-env var to ``jax.config`` (see ``_honor_jax_platforms_env``). This restores
-stock JAX semantics under TPU plugins that pin the platform at interpreter
-startup; embedding applications that manage ``jax.config`` themselves should
-unset ``JAX_PLATFORMS`` or initialize their backend before importing.
 """
 
 __version__ = "0.1.0"
 
-
-def _honor_jax_platforms_env() -> None:
-    """Restore standard ``JAX_PLATFORMS`` semantics under plugin pinning.
-
-    Some TPU plugins import jax at interpreter startup and pin the platform
-    via ``jax.config``, which silently overrides a user's
-    ``JAX_PLATFORMS=cpu`` — scripts then hang on an unreachable device
-    instead of using the requested backend. If the env var is set, the
-    backend is not yet initialized, and the pinned config disagrees,
-    re-apply the env var (exactly what stock JAX would have done).
-    """
-    import sys
-
-    # registry import stays inside the function: transport-only CLIs pay
-    # nothing extra, and the accessor keeps the knob lint's single-reader
-    # invariant airtight (JAX_PLATFORMS is declared external in KNOBS)
-    from skyline_tpu.analysis.registry import env_str
-
-    want = env_str("JAX_PLATFORMS")
-    if not want:
-        return
-    # only repair when a plugin ALREADY imported jax at interpreter startup
-    # (that's the pinning scenario); if jax isn't loaded, its own lazy init
-    # honors the env var natively — and transport-only CLIs (producer,
-    # broker, collector) skip the ~2 s jax import entirely
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return
-    try:
-        import jax._src.xla_bridge as _xb
-
-        backend_live = bool(_xb._backends)
-    except (ImportError, AttributeError):
-        # a JAX-internal rename broke the probe: warn loudly instead of
-        # silently disabling the workaround
-        import warnings
-
-        warnings.warn(
-            "skyline_tpu: cannot probe JAX backend state "
-            "(jax._src.xla_bridge._backends moved?); JAX_PLATFORMS may be "
-            "ignored if a plugin pinned the platform",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        return
-    if not backend_live and jax.config.jax_platforms != want:
-        jax.config.update("jax_platforms", want)
-
-
-_honor_jax_platforms_env()

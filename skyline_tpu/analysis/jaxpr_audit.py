@@ -47,7 +47,7 @@ DEFAULT_DIMS = (2, 4, 8)
 def _iter_jaxprs(jaxpr):
     """Yield ``jaxpr`` and every sub-jaxpr reachable through eqn params
     (scan/while bodies, cond branches, pjit calls, custom_jvp, ...)."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     seen = []
     stack = [jaxpr]
@@ -60,9 +60,9 @@ def _iter_jaxprs(jaxpr):
         for eqn in j.eqns:
             for v in eqn.params.values():
                 for cand in v if isinstance(v, (tuple, list)) else (v,):
-                    if isinstance(cand, jax.core.ClosedJaxpr):
+                    if isinstance(cand, ClosedJaxpr):
                         stack.append(cand.jaxpr)
-                    elif isinstance(cand, jax.core.Jaxpr):
+                    elif isinstance(cand, Jaxpr):
                         stack.append(cand)
 
 

@@ -165,17 +165,6 @@ KNOBS: tuple[Knob, ...] = (
        "two, floored at 1024 on the Pallas path)", "engine",
        runbook="§2t"),
     # -- utils -------------------------------------------------------------
-    _k("SKYLINE_COMPILE_CACHE", "str", None,
-       "persistent XLA compilation cache directory (default: repo-local "
-       ".jax_cache in a source checkout)", "utils"),
-    _k("SKYLINE_PROBE_CACHE_TTL_S", "float", 3600.0,
-       "TTL of the cross-process backend-probe verdict file under "
-       "artifacts/ (0 disables)", "utils/probe"),
-    _k("SKYLINE_PROBE_TIMEOUT_S", "float", 150.0,
-       "backend-probe subprocess timeout", "utils/probe"),
-    _k("BENCH_PROBE_TIMEOUT", "float", 150.0,
-       "legacy alias of SKYLINE_PROBE_TIMEOUT_S (lower precedence)",
-       "utils/probe"),
     # -- multihost ---------------------------------------------------------
     _k("SKYLINE_COORDINATOR", "str", None,
        "jax.distributed coordinator address for multi-host runs",
@@ -186,12 +175,6 @@ KNOBS: tuple[Knob, ...] = (
     _k("SKYLINE_PROCESS_ID", "int", None,
        "jax.distributed process id (None = auto-detect)",
        "parallel/multihost"),
-    # -- driver entry (__graft_entry__.py) ---------------------------------
-    _k("SKYLINE_DRYRUN_FORCE_CPU", "bool", False,
-       "skip the hardware probe in dryrun_multichip and emulate on CPU",
-       "driver"),
-    _k("SKYLINE_DRYRUN_PROBE_TIMEOUT", "float", 60.0,
-       "backend-probe timeout inside dryrun_multichip", "driver"),
     # -- job flags (utils/config.py; SKYLINE_<FLAG> overrides the default,
     #    the CLI flag overrides both; defaults live on JobConfig) ----------
     _k("SKYLINE_PARALLELISM", "int", 4, "worker parallelism", "job flag",
@@ -620,15 +603,9 @@ KNOBS: tuple[Knob, ...] = (
        "the rolling baseline (per-metric rules can override)",
        "telemetry", runbook="§2o"),
     # -- bench harness (bench.py) ------------------------------------------
-    _k("BENCH_N", "int", None,
-       "window rows (default 1M on TPU, BENCH_CPU_N on the fallback)",
-       "bench"),
-    _k("BENCH_CPU_N", "int", 131072, "window rows for the CPU fallback",
-       "bench"),
+    _k("BENCH_N", "int", 1_000_000, "window rows", "bench"),
     _k("BENCH_D", "int", 8, "tuple dimensionality", "bench"),
-    _k("BENCH_WINDOWS", "int", None,
-       "measured windows (default 5 on TPU, 1 on the CPU fallback)",
-       "bench"),
+    _k("BENCH_WINDOWS", "int", 5, "measured windows", "bench"),
     _k("BENCH_PARALLELISM", "int", 4, "engine parallelism", "bench"),
     _k("BENCH_ALGO", "str", "mr-angle", "partitioner for the bench run",
        "bench"),
@@ -686,21 +663,14 @@ KNOBS: tuple[Knob, ...] = (
        "ops-leg journal appends timed for the per-record cost", "bench"),
     _k("BENCH_SERVE_POINTS", "bool", False,
        "serve-leg full-payload reads instead of metadata-only", "bench"),
-    _k("BENCH_COMPILE_CACHE", "str", None,
-       "persistent compile-cache dir override for bench children", "bench"),
-    _k("BENCH_PROBE_ATTEMPTS", "int", 2, "backend-probe attempts", "bench"),
-    _k("BENCH_PROBE_BACKOFF", "float", 20.0,
-       "seconds between probe attempts", "bench"),
-    _k("BENCH_CHILD_TIMEOUT", "float", 3000.0,
-       "bounded child-run timeout in seconds", "bench"),
-    _k("BENCH_TPU_ATTEMPTS", "int", 2, "TPU child-run attempts", "bench"),
-    _k("BENCH_FORCE_CPU", "bool", False, "skip the TPU leg entirely",
-       "bench"),
     # -- external (owned by JAX/XLA; declared for lint coverage) -----------
     _k("JAX_PLATFORMS", "str", None, "JAX backend selection (external)",
        "external", external=True),
     _k("XLA_FLAGS", "str", None, "XLA runtime flags (external)",
        "external", external=True),
+    _k("JAX_COMPILATION_CACHE_DIR", "str", None,
+       "JAX persistent compilation cache directory (external; when unset "
+       "the repo uses <checkout>/.jax_cache)", "external", external=True),
 )
 
 _BY_NAME: dict[str, Knob] = {k.name: k for k in KNOBS}

@@ -1245,7 +1245,6 @@ def main(argv=None):
             serve_config=cfg.serve_config(),
         )
     # restarted workers reuse every previously compiled executable
-    # (SKYLINE_COMPILE_CACHE overrides the location)
     enable_compile_cache()
     bus = KafkaBus(cfg.bootstrap)
     worker = SkylineWorker(

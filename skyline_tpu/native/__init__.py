@@ -1,4 +1,4 @@
-"""Native fast-path loader (ctypes): builds fastcsv.so on first use.
+"""Native fast-path loader (ctypes): builds the fastcsv library on first use.
 
 ``parse_tuples_native(text, dims)`` parses a newline-joined batch of
 data-plane lines into (ids, values, dropped) measured 11-13x faster than
@@ -12,7 +12,9 @@ hard-requires the native component.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -20,17 +22,48 @@ import numpy as np
 
 _HERE = os.path.dirname(__file__)
 _SRC = os.path.join(_HERE, "fastcsv.cpp")
-_SO = os.path.join(_HERE, "fastcsv.so")
+_CMD = ["g++", "-O3", "-march=native", "-shared", "-fPIC"]
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build() -> bool:
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-o", _SO, _SRC]
+def _host_fingerprint() -> bytes:
+    """What ``-march=native`` compiles for: the host and its CPU model and
+    flags. A ``.so`` copied here from another machine never matches."""
+    parts = [platform.node(), platform.machine()]
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("model name", "flags", "Features")):
+                    parts.append(line.strip())
+                if len(parts) >= 4:
+                    break
+    except OSError:
+        pass
+    return "\n".join(parts).encode()
+
+
+def _so_path() -> str:
+    """The library built from the committed source with ``_CMD`` on this
+    host: the name carries a hash of all three."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CMD).encode())
+    h.update(_host_fingerprint())
+    return os.path.join(_HERE, f"fastcsv-{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> bool:
+    tmp = f"{so}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            [*_CMD, "-o", tmp, _SRC], check=True, capture_output=True,
+            timeout=120,
+        )
+        os.replace(tmp, so)  # atomic: concurrent builders never see half
         return True
     except (OSError, subprocess.SubprocessError):
         return False
@@ -45,11 +78,11 @@ def get_lib():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-            if not _build():
-                return None
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
+            return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError:
             return None
         lib.sky_parse_tuples.restype = ctypes.c_int64

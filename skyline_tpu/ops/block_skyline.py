@@ -288,11 +288,9 @@ def skyline_large(
     true skyline size (O(N*(S+B)) total) without ever stalling the dispatch
     pipeline on a high-latency device link. The old per-block-synced XLA
     form measured 74 s on the 1M x 8D anti-correlated window
-    (artifacts/kernels_tpu.json); this form runs the same kernels/shapes as
-    the engine's SFS flush, which does that window's whole local phase in
-    ~4.9 s (artifacts/bench_tpu.json phase_breakdown_ms) — the refreshed
-    skyline_large row lands in kernels_tpu.json with the next TPU
-    microbench run.
+    (artifacts/kernels_tpu.json, July 2026); this form runs the same
+    kernels/shapes as the engine's SFS flush. Its own time on the chip is
+    not measured yet.
 
     ``block=0`` scales the block with N on TPU (the same heuristic as the
     streaming engine's skewed-partition path: fewer dispatches for big

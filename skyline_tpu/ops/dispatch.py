@@ -25,8 +25,7 @@ def rank_cascade() -> bool:
     Default OFF until the hardware A/B lands: the op-count argument (2 vs 3
     VPU ops/dim) favors ranks, but rank_transform's two sorts + searchsorted
     per pass are unmeasured on TPU — run ``benchmarks/rank_cascade.py``
-    (queued in scripts/tpu_round5_measure.sh, writes
-    artifacts/rank_cascade_ab.json) and flip the default only on a >=1.15x
+    on the chip (writes artifacts/rank_cascade_ab.json) and flip the default only on a >=1.15x
     measured win. Read lazily at trace time; already-compiled executables
     are unaffected by later changes."""
     from skyline_tpu.analysis.registry import env_bool
@@ -219,9 +218,10 @@ def mixed_precision_enabled() -> bool:
     """``SKYLINE_MIXED_PRECISION`` gates the bf16 margin pass inside the
     flush dominance kernels (``ops/sfs.py``, ``ops/pallas_dominance.py``,
     ``stream/window.py`` merge steps): pairs decided OUTSIDE an explicit
-    bf16 error margin are final (bf16 runs at ~2× VPU throughput), only
-    ambiguous pairs re-run in f32, so the result is bit-exact vs the pure
-    f32 kernels (margin-correctness argument in RUNBOOK §2g). Default: ON
+    bf16 error margin are final, only ambiguous pairs re-run in f32, so the result is bit-exact vs the pure
+    f32 kernels (margin-correctness argument in RUNBOOK §2g). v5e has no
+    bf16 VPU, so there the pass rounds to bf16 and compares in f32; whether
+    it pays on the chip is not measured yet (PERF.md). Default: ON
     on TPU, OFF elsewhere — XLA's CPU backend EMULATES bf16 (upcast +
     round-trip per op), which turns the "cheap" margin pass into a ~4×
     merge-kernel pessimization on the fallback (measured at n=128K 8D:
@@ -471,7 +471,7 @@ def _is_concrete(x) -> bool:
     tracer — the jit boundary the host path must never cross."""
     import jax
 
-    return not isinstance(x, jax.core.Tracer)
+    return jax.core.is_concrete(x)
 
 
 def skyline_mask_auto(x, valid=None):

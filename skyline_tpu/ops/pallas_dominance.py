@@ -28,9 +28,9 @@ ONE precomputed rank-sum compare per pair: ``a dominates b  <=>
 max_k(ra_k - rb_k) <= 0  AND  rsum_a < rsum_b`` (all-<= with equal sums
 forces equality in every dim since each term is <=). That is 2 VPU ops per
 dim + 2 instead of 3 per dim + 2 — see ``_dom_tile_rank``. The hardware
-A/B (benchmarks/rank_cascade.py -> artifacts/rank_cascade_ab.json) is
-queued in scripts/tpu_round5_measure.sh; until it lands the value cascade
-stays the default (ops/dispatch.py).
+A/B (benchmarks/rank_cascade.py -> artifacts/rank_cascade_ab.json) has not
+run on the chip; until it does the value cascade stays the default
+(ops/dispatch.py).
 Rank sums stay exact in f32 (ranks < N <= 2^20, sums < d * N << 2^24).
 """
 
@@ -53,21 +53,22 @@ ROW_TILE = 512
 COL_TILE = 2048
 
 
-def _dom_tile(d: int, x_ref, y_ref, v_ref):
+def _dom_tile(d: int, x_ref, y_ref, v_ref, rows=slice(None), cols=slice(None)):
     """(R, C) dominance tile via the min/max reformulation:
     ``x dominates y  <=>  max_k(x_k - y_k) <= 0  AND  min_k(x_k - y_k) < 0``
     — 3 f32 VPU ops per dimension (sub, max, min) instead of the naive
     4-op compare/bool chain, and the bool work collapses to one pair of
     compares per tile. Measured ~1.6x the bool-chain kernel
-    (artifacts/kernels_tpu.json)."""
-    diff = x_ref[0, :][:, None] - y_ref[0, :][None, :]
+    (artifacts/kernels_tpu.json). ``rows`` / ``cols`` pick a sub-block of
+    the tile (the mixed-precision body rechecks one block at a time)."""
+    diff = x_ref[0, rows][:, None] - y_ref[0, cols][None, :]
     mx = diff
     mn = diff
     for k in range(1, d):  # static unroll over dimensions
-        dk = x_ref[k, :][:, None] - y_ref[k, :][None, :]
+        dk = x_ref[k, rows][:, None] - y_ref[k, cols][None, :]
         mx = jnp.maximum(mx, dk)
         mn = jnp.minimum(mn, dk)
-    vmask = v_ref[0, :][:, None] > 0.5  # (R, 1) from a 32-bit load
+    vmask = v_ref[0, rows][:, None] > 0.5  # (R, 1) from a 32-bit load
     return (mx <= 0.0) & (mn < 0.0) & vmask
 
 
@@ -82,55 +83,85 @@ _BF16_K_EPS = 0.015625  # 2^-6
 _BF16_K_TINY = 1e-30
 
 
-def _dom_tile_mp(d: int, x_ref, y_ref, v_ref):
-    """bf16 trilean classification of one (R, C) tile: returns
-    ``(certain, undecided)`` where ``certain[i, j]`` certifies f32 STRICT
-    dominance (every dim below the margin band) and ``undecided[i, j]``
-    marks pairs inside the band in some dim with no dim certainly greater —
-    only those need the f32 recheck. Pairs with a certainly-greater dim are
-    final non-dominators (x_k > y_k in f32 kills all(<=)). All compares run
-    in bf16 (~2x VPU throughput vs f32). NaN coords fail every margin test
-    -> undecided -> f32 recheck (conservative); +inf dominator rows get
-    diff = +inf > margin -> certainly-greater -> decided inert."""
-    bf = jnp.bfloat16
-    xb = x_ref[0, :].astype(bf)[:, None]
-    yb = y_ref[0, :].astype(bf)[None, :]
-    m = _BF16_K_EPS * (jnp.abs(xb) + jnp.abs(yb)) + _BF16_K_TINY
-    diff = xb - yb
+def _dom_tile_mp(d: int, x_ref, y_ref, v_ref, rows, cols):
+    """bf16 trilean classification of one (S, B) block of (dominator,
+    victim) pairs: returns ``(certain, undecided)`` where
+    ``certain[i, j]`` certifies f32 STRICT dominance (every dim below the
+    margin band) and ``undecided`` says whether some pair lies inside the
+    band in some dim with no dim certainly greater — only then does the
+    block need the f32 recheck. Pairs with a certainly-greater dim are
+    final non-dominators (x_k > y_k in f32 kills all(<=)). NaN coords fail
+    every margin test -> undecided -> f32 recheck (conservative); +inf
+    dominator rows get diff = +inf > margin -> certainly-greater -> decided
+    inert.
+
+    Mosaic constraints (v5e): each coordinate is loaded as a 2-D f32 value
+    and cast to bf16 afterwards (a 1-D bf16 -> column reshape has no
+    layout), and the undecided flag is a 32-bit reduction (a scalar
+    reduction of an i1 tile cannot be relaid out)."""
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def margin_diff(k):
+        xb = x_ref[k, rows][:, None].astype(bf)  # (S, 1)
+        yb = y_ref[k, cols][None, :].astype(bf)  # (1, B)
+        m = _BF16_K_EPS * (jnp.abs(xb) + jnp.abs(yb)) + _BF16_K_TINY
+        # bf16 values compared in f32: same verdicts, and Mosaic has no
+        # layout for a bf16 compare's mask on v5e
+        return (xb - yb).astype(f32), m.astype(f32)
+
+    diff, m = margin_diff(0)
     all_lt = diff < -m
     any_gt = diff > m
     for k in range(1, d):  # static unroll over dimensions
-        xb = x_ref[k, :].astype(bf)[:, None]
-        yb = y_ref[k, :].astype(bf)[None, :]
-        m = _BF16_K_EPS * (jnp.abs(xb) + jnp.abs(yb)) + _BF16_K_TINY
-        dk = xb - yb
+        dk, m = margin_diff(k)
         all_lt = all_lt & (dk < -m)
         any_gt = any_gt | (dk > m)
-    vmask = v_ref[0, :][:, None] > 0.5
-    certain = all_lt & vmask
-    undecided = jnp.logical_not(all_lt | any_gt) & vmask
+    vcol = v_ref[0, rows][:, None]  # (S, 1) validity as 1.0 / 0.0
+    certain = all_lt & (vcol > 0.5)
+    open_pairs = jnp.where(all_lt | any_gt, 0.0, 1.0) * vcol
+    undecided = jnp.max(open_pairs) > 0.5
     return certain, undecided
+
+
+# (dominator rows, victim cols) per block of the mixed-precision body.
+# Bounds the live intermediates: the whole (512, 2048) tile at once needs
+# ~49 MiB of scoped VMEM on v5e (limit 16 MiB). Lane slices must stay
+# 128-aligned.
+_MP_BLOCK = (128, 512)
 
 
 def _tile_body(d: int, mp: bool, x_ref, y_ref, v_ref, out_ref):
     """Shared compute body of the value-cascade kernels: with ``mp`` the
-    bf16 margin pass decides the tile first and the f32 cascade reruns only
-    when some pair lands inside the margin band. Exact either way: a fully
-    decided tile's certain set IS the f32 dominator set (decided-false
-    pairs have a strictly-greater dim), and an ambiguous tile ORs in the
-    full f32 verdict (a superset of its certain pairs)."""
-    if mp:
-        certain, undecided = _dom_tile_mp(d, x_ref, y_ref, v_ref)
-        out_ref[...] = out_ref[...] | certain.any(axis=0, keepdims=True)
-
-        @pl.when(undecided.any())
-        def _exact():
-            dom = _dom_tile(d, x_ref, y_ref, v_ref)
-            out_ref[...] = out_ref[...] | dom.any(axis=0, keepdims=True)
-
-    else:
+    bf16 margin pass decides each block of pairs first and the f32 cascade
+    reruns on that block only when some pair lands inside the margin band.
+    Exact either way: a fully decided block's certain set IS the f32
+    dominator set (decided-false pairs have a strictly-greater dim), and an
+    ambiguous block ORs in the full f32 verdict (a superset of its certain
+    pairs)."""
+    if not mp:
         dom = _dom_tile(d, x_ref, y_ref, v_ref)
         out_ref[...] = out_ref[...] | dom.any(axis=0, keepdims=True)
+        return
+
+    r, c = x_ref.shape[1], y_ref.shape[1]
+    sr, sc = min(_MP_BLOCK[0], r), min(_MP_BLOCK[1], c)
+    n_row_blocks = r // sr
+
+    def block(b, carry):
+        i, j = b % n_row_blocks, b // n_row_blocks
+        rows = pl.ds(pl.multiple_of(i * sr, sr), sr)
+        cols = pl.ds(pl.multiple_of(j * sc, sc), sc)
+        certain, undecided = _dom_tile_mp(d, x_ref, y_ref, v_ref, rows, cols)
+        out_ref[:, cols] = out_ref[:, cols] | certain.any(axis=0, keepdims=True)
+
+        @pl.when(undecided)
+        def _exact():
+            dom = _dom_tile(d, x_ref, y_ref, v_ref, rows, cols)
+            out_ref[:, cols] = out_ref[:, cols] | dom.any(axis=0, keepdims=True)
+
+        return carry
+
+    jax.lax.fori_loop(0, n_row_blocks * (c // sc), block, 0)
 
 
 def _tile_sum_skip(d: int, x_ref, y_ref, v_ref):

@@ -18,17 +18,21 @@ import jax
 
 
 def chip_devices(chips: int) -> list:
-    """The device backing each chip index, round-robined over the local
-    device list.
+    """The device backing each chip index.
 
-    With at least ``chips`` devices each group gets its own chip; with
-    fewer (a plain 1-CPU bench run, or more groups than hardware) the
-    groups wrap — correctness never depends on the placement, only
-    locality does, so oversubscription degrades bandwidth, not bytes.
+    On an accelerator each group needs its own chip: asking for more chips
+    than the host has raises. On the CPU backend (tier-1's virtual devices,
+    or a plain 1-CPU run) the groups round-robin over the local devices —
+    correctness never depends on the placement, only locality does.
     """
     if chips < 1:
         raise ValueError(f"chips must be >= 1, got {chips}")
     devs = jax.devices()
+    if len(devs) < chips and devs[0].platform != "cpu":
+        raise ValueError(
+            f"{chips} chips asked for, but only {len(devs)} "
+            f"{devs[0].platform} devices exist"
+        )
     return [devs[c % len(devs)] for c in range(chips)]
 
 

@@ -30,13 +30,14 @@ from skyline_tpu.ops.block_skyline import (
     dominated_by_blocked,
     skyline_mask_blocked,
 )
-from skyline_tpu.utils.jax_compat import shard_map
 
 AXIS = "p"
 
 
 def make_mesh(n_devices: int | None = None, axis: str = AXIS) -> Mesh:
-    """1-D device mesh over the first ``n_devices`` local devices.
+    """1-D device mesh over the first ``n_devices`` local devices (on an
+    accelerator, fewer than ``n_devices`` raises; the CPU backend takes what
+    it has).
 
     The reference's analogue is Flink ``env.setParallelism(p)``
     (FlinkSkyline.java:80); here parallel workers are mesh devices and the
@@ -45,6 +46,11 @@ def make_mesh(n_devices: int | None = None, axis: str = AXIS) -> Mesh:
     """
     devices = jax.devices()
     if n_devices is not None:
+        if len(devices) < n_devices and devices[0].platform != "cpu":
+            raise ValueError(
+                f"a {n_devices}-device mesh needs {n_devices} devices, but "
+                f"only {len(devices)} {devices[0].platform} devices exist"
+            )
         devices = devices[:n_devices]
     return Mesh(np.asarray(devices), (axis,))
 
@@ -93,7 +99,7 @@ def build_two_phase(
 
         return step
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),

@@ -33,7 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from skyline_tpu.ops.block_skyline import dominated_by_blocked, skyline_mask_blocked
 from skyline_tpu.ops.dominance import compact
-from skyline_tpu.utils.jax_compat import shard_map
 
 HOST_AXIS = "host"
 CHIP_AXIS = "chip"
@@ -170,7 +169,7 @@ def build_hierarchical_two_phase(
         overflowed = lax.psum(lax.psum(overflow, CHIP_AXIS), HOST_AXIS)
         return host_keep, global_keep, overflowed
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(P((HOST_AXIS, CHIP_AXIS)), P((HOST_AXIS, CHIP_AXIS))),

@@ -158,9 +158,12 @@ def test_skyline_mask_scan_with_padding(rng):
     assert_same_set(np.asarray(vals)[keep], skyline_np(x))
 
 
-def test_skyline_mask_pallas_interpret_matches_dense(rng):
+@pytest.mark.parametrize("mp", [False, True])
+def test_skyline_mask_pallas_interpret_matches_dense(rng, mp):
     # Pallas kernels run in interpret mode on CPU: validates kernel logic
-    # (incl. the triangular skip + sum-sort wrapper) without TPU hardware
+    # (incl. the triangular skip + sum-sort wrapper) without TPU hardware.
+    # mp=True runs the bf16 margin pass block by block with the f32 recheck
+    # on undecided blocks; integer duplicates force the recheck.
     from skyline_tpu.ops.pallas_dominance import (
         dominated_by_pallas,
         skyline_mask_pallas,
@@ -169,7 +172,12 @@ def test_skyline_mask_pallas_interpret_matches_dense(rng):
 
     x = rng.uniform(0, 1000, size=(1500, 4)).astype(np.float32)
     dense = np.asarray(skyline_mask(jnp.asarray(x)))
-    pallas = np.asarray(skyline_mask_pallas(jnp.asarray(x), interpret=True))
+    pallas = np.asarray(skyline_mask_pallas(jnp.asarray(x), interpret=True, mp=mp))
+    np.testing.assert_array_equal(dense, pallas)
+
+    xi = np.floor(x[:, :3] / 50).astype(np.float32)  # ties in every dim
+    dense = np.asarray(skyline_mask(jnp.asarray(xi)))
+    pallas = np.asarray(skyline_mask_pallas(jnp.asarray(xi), interpret=True, mp=mp))
     np.testing.assert_array_equal(dense, pallas)
 
     xd = rng.uniform(0, 1000, size=(512, 4)).astype(np.float32)
@@ -178,7 +186,8 @@ def test_skyline_mask_pallas_interpret_matches_dense(rng):
     a = np.asarray(dominated_by(jnp.asarray(yv), jnp.asarray(xd), jnp.asarray(xv)))
     b = np.asarray(
         dominated_by_pallas(
-            jnp.asarray(xd.T), jnp.asarray(xv), jnp.asarray(yv.T), interpret=True
+            jnp.asarray(xd.T), jnp.asarray(xv), jnp.asarray(yv.T),
+            interpret=True, mp=mp,
         )
     )
     np.testing.assert_array_equal(a, b)

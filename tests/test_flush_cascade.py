@@ -183,10 +183,6 @@ def test_meshed_engine_cascade(monkeypatch, policy):
     """Under a mesh the grid prefilter self-disables (host rows feed a
     sharded flush) but the bf16 pass runs inside the shard_map kernels —
     results must match the cascade-off meshed run exactly."""
-    import jax
-
-    if not hasattr(jax, "shard_map"):  # same gap that fails test_engine_mesh
-        pytest.skip("jax.shard_map unavailable in this jax version")
 
     def run(on):
         _cascade_env(monkeypatch, on)

@@ -64,3 +64,31 @@ def test_two_phase_with_invalid_rows(rng):
     np.testing.assert_allclose(
         _sorted_rows(vals[gk]), _sorted_rows(skyline_np(x))
     )
+
+
+class _FakeTpu:
+    platform = "tpu"
+
+    def __init__(self, i):
+        self.id = i
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_too_few_devices_raise_on_accelerators_only(monkeypatch, platform):
+    """Asking for more chips than an accelerator host has raises (chip
+    groups and meshes never wrap or shrink silently there); the CPU
+    backend keeps wrapping / taking what it has."""
+    from skyline_tpu.parallel import chips, mesh
+
+    if platform == "tpu":
+        fake = [_FakeTpu(0)]
+        monkeypatch.setattr(chips.jax, "devices", lambda: fake)
+        with pytest.raises(ValueError, match="only 1 tpu"):
+            chips.chip_devices(4)
+        with pytest.raises(ValueError, match="only 1 tpu"):
+            mesh.make_mesh(4)
+    else:
+        devs = jax.devices()[:1]
+        monkeypatch.setattr(chips.jax, "devices", lambda: devs)
+        assert chips.chip_devices(4) == devs * 4
+        assert mesh.make_mesh(4).devices.size == 1

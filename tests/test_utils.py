@@ -146,51 +146,3 @@ def test_checkpoint_lazy_policy_roundtrip(rng, tmp_path):
     assert r["skyline_size"] == oracle.shape[0]
     got = np.asarray(r["skyline_points"])
     assert set(map(tuple, got.round(3))) == set(map(tuple, oracle.round(3)))
-
-
-def test_backend_probe_file_cache(monkeypatch, tmp_path):
-    """Probe verdicts persist across processes (ISSUE 5 satellite): a
-    successful verdict is served from the artifacts/ file within TTL with
-    provenance stamped into probe_total_s, failures are never persisted,
-    and TTL=0 disables the file cache entirely."""
-    from skyline_tpu.utils import backend_probe as bp
-
-    cache = str(tmp_path / "probe_cache.json")
-    monkeypatch.setattr(bp, "_cache_path", lambda: cache)
-    monkeypatch.setenv("SKYLINE_PROBE_CACHE_TTL_S", "3600")
-    monkeypatch.setattr(bp, "_VERDICT", None)
-    good = {"backend": "cpu", "n_devices": 1, "attempts": 1,
-            "errors": [], "probe_s": 1.2, "probe_total_s": 1.3}
-    bp._store_file_verdict(good)
-    # fresh "process" (module global reset): served from the file, no
-    # subprocess — provenance moves the probed wall time aside
-    v = bp.probe_backend(0.001)
-    assert v["cached"] and v["cache_source"] == "file"
-    assert v["probe_total_s"] == 0.0
-    assert v["probe_total_s_probed"] == 1.3
-    assert v["backend"] == "cpu" and "cache_age_s" in v
-    # second call in the same process: process cache, provenance intact
-    v2 = bp.probe_backend(0.001)
-    assert v2["cache_source"] == "process"
-    assert v2["probe_total_s_probed"] == 1.3
-    # failure verdicts must not outlive their process
-    import os
-
-    os.remove(cache)
-    bp._store_file_verdict({"backend": None, "n_devices": 0})
-    assert not os.path.exists(cache)
-    # expired entries are ignored
-    import json as _json
-
-    bp._store_file_verdict(good)
-    with open(cache) as f:
-        rec = _json.load(f)
-    rec["ts"] -= 10_000_000
-    with open(cache, "w") as f:
-        _json.dump(rec, f)
-    assert bp._load_file_verdict() is None
-    # TTL=0 disables store and load
-    monkeypatch.setenv("SKYLINE_PROBE_CACHE_TTL_S", "0")
-    os.remove(cache)
-    bp._store_file_verdict(good)
-    assert not os.path.exists(cache)
