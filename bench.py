@@ -454,8 +454,8 @@ def serve_leg(d: int, algo: str) -> dict:
     hub = Telemetry()
     from skyline_tpu.metrics.tracing import Tracer
 
-    # non-syncing tracer: supplies the flush/merge_kernel phase total the
-    # profiler attributes its per-signature wall time against
+    # the tracer supplies the flush/merge_kernel phase total the profiler
+    # attributes its per-signature wall time against
     eng = SkylineEngine(
         EngineConfig(parallelism=2, algo=algo, dims=d, domain_max=10000.0,
                      flush_policy="lazy"),
@@ -855,12 +855,12 @@ def main() -> None:
     x = anti_correlated(rng, n, d, 0, 10000)
     warm_dt, warm_res = run_window(cfg, ids, x, required)
 
-    # profile window: same workload with a device-syncing Tracer so the
-    # bench JSON carries the per-phase anatomy of a window (syncs distort
-    # pipelining, so this window is NOT included in the measured latencies)
+    # profile window: same workload with a Tracer so the bench JSON carries
+    # the per-phase host anatomy of a window (kept out of the measured
+    # latencies; device time lands in the phases that wait on it)
     from skyline_tpu.metrics.tracing import Tracer
 
-    tracer = Tracer(sync_device=True)
+    tracer = Tracer()
     prof_dt, _ = run_window(
         cfg, ids, anti_correlated(rng, n, d, 0, 10000), required, tracer=tracer
     )

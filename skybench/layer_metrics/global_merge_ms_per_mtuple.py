@@ -1,0 +1,9 @@
+"""Host self time of the program's ``merge`` layer, the global merge
+(``global_merge*``, ``query/*``), in the traced sub-window, in ms per
+million tuples answered in it (``skybench/program_spans.py``)."""
+
+from skybench.program_spans import ms_per_mtuple
+
+
+def read(record: dict):
+    return ms_per_mtuple(record, "merge")

@@ -147,43 +147,42 @@ class Auditor:
                 trace_id=trace_id,
             )
             return None
-        t0 = time.perf_counter_ns()
-        skies, _ = self.engine.pset.audit_state()
-        union = (
-            np.concatenate([s for s in skies], axis=0)
-            if skies
-            else np.empty((0, self.engine.pset.dims), dtype=np.float32)
-        )
-        from skyline_tpu.audit.oracle import oracle_fn, oracle_kind
+        # checks and divergences join /explain and /trace via the audited
+        # snapshot's trace_id
+        with self.engine.tracer.span_phase(
+            "audit/check", tel.spans, "audit/check", trace_id=trace_id, tid=4,
+        ) as ph:
+            skies, _ = self.engine.pset.audit_state()
+            union = (
+                np.concatenate([s for s in skies], axis=0)
+                if skies
+                else np.empty((0, self.engine.pset.dims), dtype=np.float32)
+            )
+            from skyline_tpu.audit.oracle import oracle_fn, oracle_kind
 
-        oracle = np.asarray(oracle_fn()(union), dtype=np.float32)
-        published = np.asarray(snap.points, dtype=np.float32)
-        diff = first_diff(published, oracle)
-        ok = diff is None
-        tel.inc("audit.checks")
-        record = {
-            "kind": "organic",
-            "ok": ok,
-            "trace_id": trace_id,
-            "version": int(snap.version),
-            "digest": snap.digest,
-            "oracle": oracle_kind(),
-            "published_rows": int(published.shape[0]),
-            "oracle_rows": int(oracle.shape[0]),
-            "first_diff": diff,
-            "bundle": None,
-        }
-        if not ok:
-            tel.inc("audit.divergence")
-            record["bundle"] = self._freeze_bundle(snap, oracle, diff, q)
-        tel.audit.add(record)
-        # satellite: checks and divergences join /explain and /trace via
-        # the audited snapshot's trace_id
-        tel.spans.record(
-            "audit/divergence" if not ok else "audit/check",
-            t0, time.perf_counter_ns(), trace_id=trace_id, tid=4,
-            args={"version": int(snap.version), "ok": ok},
-        )
+            oracle = np.asarray(oracle_fn()(union), dtype=np.float32)
+            published = np.asarray(snap.points, dtype=np.float32)
+            diff = first_diff(published, oracle)
+            ok = diff is None
+            tel.inc("audit.checks")
+            record = {
+                "kind": "organic",
+                "ok": ok,
+                "trace_id": trace_id,
+                "version": int(snap.version),
+                "digest": snap.digest,
+                "oracle": oracle_kind(),
+                "published_rows": int(published.shape[0]),
+                "oracle_rows": int(oracle.shape[0]),
+                "first_diff": diff,
+                "bundle": None,
+            }
+            if not ok:
+                tel.inc("audit.divergence")
+                record["bundle"] = self._freeze_bundle(snap, oracle, diff, q)
+                ph.span = "audit/divergence"
+            tel.audit.add(record)
+            ph.args = {"version": int(snap.version), "ok": ok}
         tel.flight.note(
             "audit.divergence" if not ok else "audit.check",
             ok=ok, version=int(snap.version), trace_id=trace_id,

@@ -77,9 +77,10 @@ class SkylineWorker:
         ``serve_config``: a ``serve.ServeConfig`` overriding the admission
         and ring knobs (its ``port`` is overridden by ``serve_port``).
         ``tracer``: optional ``metrics.tracing.Tracer``; by default the
-        worker traces its own loop (transport poll / parse / engine phases)
-        with ``sync_device=False`` so the breakdown is observable in
-        ``/stats`` without perturbing the async device pipeline.
+        worker traces its own loop (build / transport poll / parse / engine
+        phases) so the breakdown is observable in ``/stats``, and in any
+        JAX profiler capture of the process, without perturbing the async
+        device pipeline.
         ``telemetry``: optional shared ``telemetry.Telemetry`` hub; the
         worker always has one (created here when not given, span ring sized
         ``trace_ring``) and threads it through the engine and both HTTP
@@ -117,377 +118,378 @@ class SkylineWorker:
         self.cluster_hosts = int(cluster_hosts)
         self.bus = bus
         self.max_drain_polls = max_drain_polls
-        self.tracer = tracer if tracer is not None else Tracer(sync_device=False)
-        self.telemetry = (
-            telemetry if telemetry is not None
-            else Telemetry(span_capacity=trace_ring)
-        )
-        self.trace_out = trace_out
-        self._jax_profile_dir = jax_profile_dir
-        self._phase_snapshot_ms: dict[str, float] = {}
-        self._last_phase_report_s = 0.0
-        # None = undecided, True = zero-copy array plane, False = line plane
-        self._arrays_plane: bool | None = None
-        # (ids, values) tail of an oversized array batch, served in
-        # max_records micro-batches by subsequent _poll_data calls
-        self._data_carry: tuple | None = None
-        # -- crash safety (resilience=None keeps all of this inert) -------
-        self.resilience = resilience
-        self._ckpt_mgr = None
-        self._wal = None
-        self._chip_wal = None
-        self._lease_plane = None
-        self._lease_keeper = None
-        self._opslog = None
-        self._deposed = False
-        self._snap_store = None
-        self._serve_ring = None
-        self._bodystore = None
-        self._data_pos = 0  # consumed data-topic records (replay currency)
-        self._query_pos = 0  # consumed query-topic records
-        self._dirty = False  # work since the last checkpoint
-        self._last_ckpt_s = time.monotonic()
-        self._stop_requested = False
-        self._recovered: dict | None = None
-        restored_engine = None
-        restored_meta = None
-        wal_records: list = []
-        wal_torn = 0
-        if resilience is not None:
-            if window_size:
-                raise ValueError(
-                    "sliding-window mode does not support crash safety "
-                    "(utils/checkpoint.py covers the tumbling engine only)"
-                )
-            install_from_env()  # arm SKYLINE_FAULT_PLAN (parse-once)
-            from skyline_tpu.resilience import WAL_SUBDIR
-            from skyline_tpu.resilience.checkpoints import CheckpointManager
-            from skyline_tpu.resilience.wal import read_records
-
-            self._ckpt_mgr = CheckpointManager(
-                resilience.checkpoint_dir,
-                retain=resilience.checkpoint_retain,
-                telemetry=self.telemetry,
+        self.tracer = tracer if tracer is not None else Tracer()
+        with self.tracer.phase("worker/build"):
+            self.telemetry = (
+                telemetry if telemetry is not None
+                else Telemetry(span_capacity=trace_ring)
             )
-            hit = self._ckpt_mgr.restore_latest(
-                mesh=mesh, mesh_chips=mesh_chips,
-                cluster_hosts=cluster_hosts, tracer=self.tracer,
-                telemetry=self.telemetry,
-            )
-            ckpt_path = None
-            if hit is not None:
-                restored_engine, restored_meta, ckpt_path = hit
-            self._wal_dir = os.path.join(resilience.checkpoint_dir, WAL_SUBDIR)
-            wal_records, wal_torn = read_records(self._wal_dir)
-            # sharded group-consistency check: at the highest barrier seq
-            # common to all chip journals, every chip must agree on the
-            # global epoch digest; divergence raises WalReplayError here,
-            # BEFORE any replay could publish from inconsistent groups
-            from skyline_tpu.resilience.chip_wal import verify_chip_barriers
-
-            chip_verdict = verify_chip_barriers(self._wal_dir)
-            if hit is not None or wal_records:
-                self._recovered = {
-                    "checkpoint": ckpt_path,
-                    "wal_records": len(wal_records),
-                    "wal_torn_segments": wal_torn,
-                    "replayed_batches": 0,
-                }
-                if chip_verdict["chips"]:
-                    self._recovered["chip_barriers"] = chip_verdict
-        if window_size:
-            from skyline_tpu.stream.sliding_engine import SlidingEngine
-
-            self.engine = SlidingEngine(
-                config,
-                window_size=window_size,
-                slide=slide,
-                mesh=mesh,
-                emit_per_slide=emit_per_slide,
-                tracer=self.tracer,
-                telemetry=self.telemetry,
-            )
-        elif restored_engine is not None:
-            # the checkpoint carries its full EngineConfig; trust it over the
-            # passed config so a restarted incarnation can't silently change
-            # result semantics mid-stream
-            self.engine = restored_engine
-        elif cluster_hosts:
-            # multi-host cluster ingest (RUNBOOK §2r): mesh_chips becomes
-            # the per-host chip count, so --cluster-hosts 4 --mesh-chips 2
-            # runs the full three-level tournament
-            from skyline_tpu.cluster import ClusterEngine
-
-            self.engine = ClusterEngine(
-                config, hosts=cluster_hosts,
-                chips_per_host=mesh_chips or 1, tracer=self.tracer,
-                telemetry=self.telemetry,
-            )
-        elif mesh_chips:
-            from skyline_tpu.distributed import ShardedEngine
-
-            self.engine = ShardedEngine(
-                config, chips=mesh_chips, tracer=self.tracer,
-                telemetry=self.telemetry,
-            )
-        else:
-            self.engine = SkylineEngine(
-                config, mesh=mesh, tracer=self.tracer, telemetry=self.telemetry
-            )
-        self.output_topic = output_topic
-        self._data = bus.consumer(input_topic, from_beginning=True)
-        self._queries = bus.consumer(query_topic, from_beginning=False)
-        self.results_emitted = 0
-        if resilience is not None:
-            # warm the learned-dispatch planes BEFORE replay so the replay
-            # flushes themselves run under the checkpointed winners
-            # instead of re-paying cold exploration (PR 18 scoping note)
-            self._restore_dispatch_state(restored_meta)
-            self._replay(restored_meta, wal_records)
-        self.serve_server = None
-        self._serve_bridge = None
-        if serve_port is not None:
-            from skyline_tpu.serve import (
-                DeltaRing,
-                QueryBridge,
-                ServeConfig,
-                SkylineServer,
-                SnapshotStore,
-            )
-
-            scfg = serve_config if serve_config is not None else ServeConfig()
-            store = SnapshotStore(history=scfg.history)
-            ring = DeltaRing(store, capacity=scfg.delta_ring)
-            self.engine.attach_snapshots(store)
-            self._serve_bridge = QueryBridge()
-            self._snap_store = store
-            self._serve_ring = ring
-            # zero-copy body store (RUNBOOK §2u): wire bodies serialize
-            # once per publish, off the read path. With resilience the
-            # store file lands beside the WAL so --replicas / --replica-of
-            # processes map the primary's exact bytes; without a WAL dir
-            # it stays in-process (publish-time serialization still wins).
-            from skyline_tpu.analysis.registry import env_bool
-
-            if env_bool("SKYLINE_BODYSTORE", True):
-                from skyline_tpu.serve.bodystore import BodyStore
-
-                wal_dir = getattr(self, "_wal_dir", None)
-                self._bodystore = BodyStore(
-                    os.path.join(wal_dir, "bodystore.dat")
-                    if wal_dir is not None
-                    else None
-                ).attach(store)
-            try:
-                self.serve_server = SkylineServer(
-                    store,
-                    deltas=ring,
-                    admission=scfg.admission(),
-                    stats_cb=self.stats,
-                    bridge=self._serve_bridge,
-                    port=serve_port,
-                    host=scfg.host,
-                    telemetry=self.telemetry,
-                    read_cache=scfg.read_cache_entries,
-                    bodystore=self._bodystore,
-                )
-            except OSError as e:
-                # like /stats: the serving plane is optional — a port
-                # conflict must not take the ingest plane down
-                self.engine.snapshots = None
-                self._serve_bridge = None
-                self._snap_store = None
-                self._serve_ring = None
-                if self._bodystore is not None:
-                    self._bodystore.close()
-                    self._bodystore = None
-                print(
-                    f"skyline worker: serve port {serve_port} unavailable "
-                    f"({e}); continuing without the serving plane",
-                    file=sys.stderr,
-                )
-        if resilience is not None:
-            if self._snap_store is not None:
-                self._restore_serve(wal_records)
-            from skyline_tpu.analysis.registry import env_float
-            from skyline_tpu.resilience.wal import WalWriter
-
-            wal_kw = dict(
-                segment_bytes=resilience.wal_segment_bytes,
-                fsync=resilience.wal_fsync,
-                telemetry=self.telemetry,
-                # live replica tailers pin segment retention (barrier skips
-                # segments they haven't consumed); stale acks expire so a
-                # dead replica can't pin the log forever
-                tailer_ttl_s=env_float("SKYLINE_WAL_TAILER_TTL_S", 600.0),
-            )
-            # durable cross-process ops journal (RUNBOOK §2s): every
-            # control-plane transition this process performs — lease
-            # acquire, demotion, quarantine, degraded publish — lands
-            # beside the WAL so a post-mortem reconstructs the fleet's
-            # causal timeline across processes
-            from skyline_tpu.telemetry.opslog import OpsLog, opslog_enabled
-
-            if opslog_enabled():
-                self._opslog = OpsLog(self._wal_dir, telemetry=self.telemetry)
-                self.telemetry.opslog = self._opslog
-                pset = getattr(self.engine, "pset", None)
-                if pset is not None and hasattr(pset, "attach_opslog"):
-                    pset.attach_opslog(self._opslog)
-            if cluster_hosts:
-                # write-path HA (RUNBOOK §2r): this worker is the lease
-                # holder; every WAL frame carries its fencing token, and
-                # the instant another primary is promoted over us every
-                # append is rejected at the WAL layer
-                from skyline_tpu.cluster import (
-                    FencedWalWriter,
-                    LeaseKeeper,
-                    LeasePlane,
-                )
-
-                self._lease_plane = LeasePlane(self._wal_dir)
-                # globally unique holder id: pid alone collides across
-                # containers (pid 1) or hosts sharing the WAL dir, and
-                # LeasePlane.acquire treats a same-named holder as self —
-                # a collision would depose a live primary instead of
-                # refusing to start
-                self._lease_keeper = LeaseKeeper(
-                    self._lease_plane,
-                    f"worker-{socket.gethostname()}-{os.getpid()}"
-                    f"-{uuid.uuid4().hex[:8]}",
-                    telemetry=self.telemetry,
-                )
-                if self._lease_keeper.acquire() is None:
-                    held = self._lease_plane.read_lease()
+            self.trace_out = trace_out
+            self._jax_profile_dir = jax_profile_dir
+            self._phase_snapshot_ms: dict[str, float] = {}
+            self._last_phase_report_s = 0.0
+            # None = undecided, True = zero-copy array plane, False = line plane
+            self._arrays_plane: bool | None = None
+            # (ids, values) tail of an oversized array batch, served in
+            # max_records micro-batches by subsequent _poll_data calls
+            self._data_carry: tuple | None = None
+            # -- crash safety (resilience=None keeps all of this inert) -------
+            self.resilience = resilience
+            self._ckpt_mgr = None
+            self._wal = None
+            self._chip_wal = None
+            self._lease_plane = None
+            self._lease_keeper = None
+            self._opslog = None
+            self._deposed = False
+            self._snap_store = None
+            self._serve_ring = None
+            self._bodystore = None
+            self._data_pos = 0  # consumed data-topic records (replay currency)
+            self._query_pos = 0  # consumed query-topic records
+            self._dirty = False  # work since the last checkpoint
+            self._last_ckpt_s = time.monotonic()
+            self._stop_requested = False
+            self._recovered: dict | None = None
+            restored_engine = None
+            restored_meta = None
+            wal_records: list = []
+            wal_torn = 0
+            if resilience is not None:
+                if window_size:
                     raise ValueError(
-                        "write lease is held by "
-                        f"{held.holder!r} (epoch {held.epoch}); refusing to "
-                        "start a second primary against the same WAL"
+                        "sliding-window mode does not support crash safety "
+                        "(utils/checkpoint.py covers the tumbling engine only)"
                     )
-                if self._opslog is not None:
-                    self._opslog.record(
-                        "lease_acquired",
-                        epoch=self._lease_keeper.epoch,
-                        fence=self._lease_plane.read_fence(),
-                        holder=self._lease_keeper.holder,
-                    )
-                self._wal = FencedWalWriter(
-                    self._wal_dir,
-                    self._lease_keeper.epoch,
-                    plane=self._lease_plane,
-                    opslog=self._opslog,
-                    **wal_kw,
+                install_from_env()  # arm SKYLINE_FAULT_PLAN (parse-once)
+                from skyline_tpu.resilience import WAL_SUBDIR
+                from skyline_tpu.resilience.checkpoints import CheckpointManager
+                from skyline_tpu.resilience.wal import read_records
+
+                self._ckpt_mgr = CheckpointManager(
+                    resilience.checkpoint_dir,
+                    retain=resilience.checkpoint_retain,
+                    telemetry=self.telemetry,
                 )
-                status = getattr(self.telemetry, "cluster", None)
-                if status is not None:
-                    status.node_id = self._lease_keeper.holder
-                    status.role = "primary"
-                    status.lease_cb = self._lease_plane.doc
+                hit = self._ckpt_mgr.restore_latest(
+                    mesh=mesh, mesh_chips=mesh_chips,
+                    cluster_hosts=cluster_hosts, tracer=self.tracer,
+                    telemetry=self.telemetry,
+                )
+                ckpt_path = None
+                if hit is not None:
+                    restored_engine, restored_meta, ckpt_path = hit
+                self._wal_dir = os.path.join(resilience.checkpoint_dir, WAL_SUBDIR)
+                wal_records, wal_torn = read_records(self._wal_dir)
+                # sharded group-consistency check: at the highest barrier seq
+                # common to all chip journals, every chip must agree on the
+                # global epoch digest; divergence raises WalReplayError here,
+                # BEFORE any replay could publish from inconsistent groups
+                from skyline_tpu.resilience.chip_wal import verify_chip_barriers
+
+                chip_verdict = verify_chip_barriers(self._wal_dir)
+                if hit is not None or wal_records:
+                    self._recovered = {
+                        "checkpoint": ckpt_path,
+                        "wal_records": len(wal_records),
+                        "wal_torn_segments": wal_torn,
+                        "replayed_batches": 0,
+                    }
+                    if chip_verdict["chips"]:
+                        self._recovered["chip_barriers"] = chip_verdict
+            if window_size:
+                from skyline_tpu.stream.sliding_engine import SlidingEngine
+
+                self.engine = SlidingEngine(
+                    config,
+                    window_size=window_size,
+                    slide=slide,
+                    mesh=mesh,
+                    emit_per_slide=emit_per_slide,
+                    tracer=self.tracer,
+                    telemetry=self.telemetry,
+                )
+            elif restored_engine is not None:
+                # the checkpoint carries its full EngineConfig; trust it over the
+                # passed config so a restarted incarnation can't silently change
+                # result semantics mid-stream
+                self.engine = restored_engine
+            elif cluster_hosts:
+                # multi-host cluster ingest (RUNBOOK §2r): mesh_chips becomes
+                # the per-host chip count, so --cluster-hosts 4 --mesh-chips 2
+                # runs the full three-level tournament
+                from skyline_tpu.cluster import ClusterEngine
+
+                self.engine = ClusterEngine(
+                    config, hosts=cluster_hosts,
+                    chips_per_host=mesh_chips or 1, tracer=self.tracer,
+                    telemetry=self.telemetry,
+                )
+            elif mesh_chips:
+                from skyline_tpu.distributed import ShardedEngine
+
+                self.engine = ShardedEngine(
+                    config, chips=mesh_chips, tracer=self.tracer,
+                    telemetry=self.telemetry,
+                )
             else:
-                self._wal = WalWriter(self._wal_dir, **wal_kw)
-            # WAL replication-plane families (RUNBOOK §2s): retained
-            # segments plus per-tailer ack age — a growing ack age is a
-            # stalled replica still pinning retention
-            def _wal_plane_series(wal=self._wal, wal_dir=self._wal_dir):
-                from skyline_tpu.resilience.wal import ack_ages_s
+                self.engine = SkylineEngine(
+                    config, mesh=mesh, tracer=self.tracer, telemetry=self.telemetry
+                )
+            self.output_topic = output_topic
+            self._data = bus.consumer(input_topic, from_beginning=True)
+            self._queries = bus.consumer(query_topic, from_beginning=False)
+            self.results_emitted = 0
+            if resilience is not None:
+                # warm the learned-dispatch planes BEFORE replay so the replay
+                # flushes themselves run under the checkpointed winners
+                # instead of re-paying cold exploration
+                self._restore_dispatch_state(restored_meta)
+                self._replay(restored_meta, wal_records)
+            self.serve_server = None
+            self._serve_bridge = None
+            if serve_port is not None:
+                from skyline_tpu.serve import (
+                    DeltaRing,
+                    QueryBridge,
+                    ServeConfig,
+                    SkylineServer,
+                    SnapshotStore,
+                )
 
-                gauges: dict = {}
-                st = wal.stats()
-                gauges["wal_segments_retained"] = [
-                    ((), float(st.get("segments_retained", 0)))
-                ]
-                ages = ack_ages_s(wal_dir)
-                if ages:
-                    gauges["wal_tail_ack_age_s"] = [
-                        ((("tailer", t),), round(age, 3))
-                        for t, age in sorted(ages.items())
-                    ]
-                return {}, gauges
+                scfg = serve_config if serve_config is not None else ServeConfig()
+                store = SnapshotStore(history=scfg.history)
+                ring = DeltaRing(store, capacity=scfg.delta_ring)
+                self.engine.attach_snapshots(store)
+                self._serve_bridge = QueryBridge()
+                self._snap_store = store
+                self._serve_ring = ring
+                # zero-copy body store (RUNBOOK §2u): wire bodies serialize
+                # once per publish, off the read path. With resilience the
+                # store file lands beside the WAL so --replicas / --replica-of
+                # processes map the primary's exact bytes; without a WAL dir
+                # it stays in-process (publish-time serialization still wins).
+                from skyline_tpu.analysis.registry import env_bool
 
-            self.telemetry.replication.append(_wal_plane_series)
-            # chip-local WAL segments for the sharded engine: per-chip
-            # flush lineage + merge-time consistency barriers (policy
-            # "merge", the default), or checkpoint-time barriers only
-            # ("checkpoint"); "off" skips the plane entirely
-            if self.mesh_chips and not cluster_hosts:
-                from skyline_tpu.ops.dispatch import chip_barrier_policy
-                from skyline_tpu.resilience.chip_wal import ChipWalPlane
+                if env_bool("SKYLINE_BODYSTORE", True):
+                    from skyline_tpu.serve.bodystore import BodyStore
 
-                policy = chip_barrier_policy()
-                if policy != "off":
-                    self._chip_wal = ChipWalPlane(
-                        self._wal_dir,
-                        self.mesh_chips,
-                        segment_bytes=resilience.wal_segment_bytes,
-                        fsync=resilience.wal_fsync,
+                    wal_dir = getattr(self, "_wal_dir", None)
+                    self._bodystore = BodyStore(
+                        os.path.join(wal_dir, "bodystore.dat")
+                        if wal_dir is not None
+                        else None
+                    ).attach(store)
+                try:
+                    self.serve_server = SkylineServer(
+                        store,
+                        deltas=ring,
+                        admission=scfg.admission(),
+                        stats_cb=self.stats,
+                        bridge=self._serve_bridge,
+                        port=serve_port,
+                        host=scfg.host,
+                        telemetry=self.telemetry,
+                        read_cache=scfg.read_cache_entries,
+                        bodystore=self._bodystore,
+                    )
+                except OSError as e:
+                    # like /stats: the serving plane is optional — a port
+                    # conflict must not take the ingest plane down
+                    self.engine.snapshots = None
+                    self._serve_bridge = None
+                    self._snap_store = None
+                    self._serve_ring = None
+                    if self._bodystore is not None:
+                        self._bodystore.close()
+                        self._bodystore = None
+                    print(
+                        f"skyline worker: serve port {serve_port} unavailable "
+                        f"({e}); continuing without the serving plane",
+                        file=sys.stderr,
+                    )
+            if resilience is not None:
+                if self._snap_store is not None:
+                    self._restore_serve(wal_records)
+                from skyline_tpu.analysis.registry import env_float
+                from skyline_tpu.resilience.wal import WalWriter
+
+                wal_kw = dict(
+                    segment_bytes=resilience.wal_segment_bytes,
+                    fsync=resilience.wal_fsync,
+                    telemetry=self.telemetry,
+                    # live replica tailers pin segment retention (barrier skips
+                    # segments they haven't consumed); stale acks expire so a
+                    # dead replica can't pin the log forever
+                    tailer_ttl_s=env_float("SKYLINE_WAL_TAILER_TTL_S", 600.0),
+                )
+                # durable cross-process ops journal (RUNBOOK §2s): every
+                # control-plane transition this process performs — lease
+                # acquire, demotion, quarantine, degraded publish — lands
+                # beside the WAL so a post-mortem reconstructs the fleet's
+                # causal timeline across processes
+                from skyline_tpu.telemetry.opslog import OpsLog, opslog_enabled
+
+                if opslog_enabled():
+                    self._opslog = OpsLog(self._wal_dir, telemetry=self.telemetry)
+                    self.telemetry.opslog = self._opslog
+                    pset = getattr(self.engine, "pset", None)
+                    if pset is not None and hasattr(pset, "attach_opslog"):
+                        pset.attach_opslog(self._opslog)
+                if cluster_hosts:
+                    # write-path HA (RUNBOOK §2r): this worker is the lease
+                    # holder; every WAL frame carries its fencing token, and
+                    # the instant another primary is promoted over us every
+                    # append is rejected at the WAL layer
+                    from skyline_tpu.cluster import (
+                        FencedWalWriter,
+                        LeaseKeeper,
+                        LeasePlane,
+                    )
+
+                    self._lease_plane = LeasePlane(self._wal_dir)
+                    # globally unique holder id: pid alone collides across
+                    # containers (pid 1) or hosts sharing the WAL dir, and
+                    # LeasePlane.acquire treats a same-named holder as self —
+                    # a collision would depose a live primary instead of
+                    # refusing to start
+                    self._lease_keeper = LeaseKeeper(
+                        self._lease_plane,
+                        f"worker-{socket.gethostname()}-{os.getpid()}"
+                        f"-{uuid.uuid4().hex[:8]}",
                         telemetry=self.telemetry,
                     )
-                    if policy == "merge":
-                        self.engine.pset.attach_chip_wal(self._chip_wal)
-            # subscribe AFTER the serve restore so re-seating the head never
-            # logs a bogus everything-entered delta
-            if self._snap_store is not None:
-                self._snap_store.on_publish(self._wal_on_publish)
-            # divergence repro bundles freeze the live WAL segment slice;
-            # without resilience the auditor's wal_dir stays None and
-            # bundles simply omit the wal/ directory
-            auditor = getattr(self.engine, "auditor", None)
-            if auditor is not None:
-                auditor.wal_dir = self._wal_dir
-            self._wal.append(
-                {
-                    "type": "start",
-                    "data_off": self._data_pos,
-                    "query_off": self._query_pos,
-                }
-            )
-            self._wal.flush(force=True)
-        # WAL-tailing read replicas (serve/replica.py): each gets its own
-        # SnapshotStore + ring + HTTP port, bootstraps from the newest
-        # barrier in the WAL and live-tails publish deltas. In-process
-        # spawn is the embedded/test mode; production runs them as separate
-        # processes (--replica-of) so an engine death leaves them serving.
-        self.replicas = []
-        if replicas:
-            if resilience is None or self._snap_store is None:
-                raise ValueError(
-                    "replicas require resilience (--checkpoint-dir) and the "
-                    "serve plane (--serve)"
-                )
-            from skyline_tpu.serve.replica import SkylineReplica
-
-            # in-process replicas share the worker's hub for the labeled
-            # replica families, the worker's ops journal, and see the
-            # primary head directly for replica_lag_versions
-            store = self._snap_store
-            for i in range(int(replicas)):
-                self.replicas.append(
-                    SkylineReplica(
+                    if self._lease_keeper.acquire() is None:
+                        held = self._lease_plane.read_lease()
+                        raise ValueError(
+                            "write lease is held by "
+                            f"{held.holder!r} (epoch {held.epoch}); refusing to "
+                            "start a second primary against the same WAL"
+                        )
+                    if self._opslog is not None:
+                        self._opslog.record(
+                            "lease_acquired",
+                            epoch=self._lease_keeper.epoch,
+                            fence=self._lease_plane.read_fence(),
+                            holder=self._lease_keeper.holder,
+                        )
+                    self._wal = FencedWalWriter(
                         self._wal_dir,
-                        port=0,
-                        serve_config=serve_config,
-                        replica_id=f"replica-{i}",
-                        telemetry=self.telemetry,
+                        self._lease_keeper.epoch,
+                        plane=self._lease_plane,
                         opslog=self._opslog,
-                        primary_head_cb=lambda s=store: s.head_version,
+                        **wal_kw,
                     )
-                )
-        self.stats_server = None
-        if stats_port is not None:
-            from skyline_tpu.metrics.httpstats import StatsServer
+                    status = getattr(self.telemetry, "cluster", None)
+                    if status is not None:
+                        status.node_id = self._lease_keeper.holder
+                        status.role = "primary"
+                        status.lease_cb = self._lease_plane.doc
+                else:
+                    self._wal = WalWriter(self._wal_dir, **wal_kw)
+                # WAL replication-plane families (RUNBOOK §2s): retained
+                # segments plus per-tailer ack age — a growing ack age is a
+                # stalled replica still pinning retention
+                def _wal_plane_series(wal=self._wal, wal_dir=self._wal_dir):
+                    from skyline_tpu.resilience.wal import ack_ages_s
 
-            try:
-                self.stats_server = StatsServer(
-                    self.stats, stats_port, telemetry=self.telemetry
+                    gauges: dict = {}
+                    st = wal.stats()
+                    gauges["wal_segments_retained"] = [
+                        ((), float(st.get("segments_retained", 0)))
+                    ]
+                    ages = ack_ages_s(wal_dir)
+                    if ages:
+                        gauges["wal_tail_ack_age_s"] = [
+                            ((("tailer", t),), round(age, 3))
+                            for t, age in sorted(ages.items())
+                        ]
+                    return {}, gauges
+
+                self.telemetry.replication.append(_wal_plane_series)
+                # chip-local WAL segments for the sharded engine: per-chip
+                # flush lineage + merge-time consistency barriers (policy
+                # "merge", the default), or checkpoint-time barriers only
+                # ("checkpoint"); "off" skips the plane entirely
+                if self.mesh_chips and not cluster_hosts:
+                    from skyline_tpu.ops.dispatch import chip_barrier_policy
+                    from skyline_tpu.resilience.chip_wal import ChipWalPlane
+
+                    policy = chip_barrier_policy()
+                    if policy != "off":
+                        self._chip_wal = ChipWalPlane(
+                            self._wal_dir,
+                            self.mesh_chips,
+                            segment_bytes=resilience.wal_segment_bytes,
+                            fsync=resilience.wal_fsync,
+                            telemetry=self.telemetry,
+                        )
+                        if policy == "merge":
+                            self.engine.pset.attach_chip_wal(self._chip_wal)
+                # subscribe AFTER the serve restore so re-seating the head never
+                # logs a bogus everything-entered delta
+                if self._snap_store is not None:
+                    self._snap_store.on_publish(self._wal_on_publish)
+                # divergence repro bundles freeze the live WAL segment slice;
+                # without resilience the auditor's wal_dir stays None and
+                # bundles simply omit the wal/ directory
+                auditor = getattr(self.engine, "auditor", None)
+                if auditor is not None:
+                    auditor.wal_dir = self._wal_dir
+                self._wal.append(
+                    {
+                        "type": "start",
+                        "data_off": self._data_pos,
+                        "query_off": self._query_pos,
+                    }
                 )
-            except OSError as e:
-                # observability is optional: a port conflict must not take
-                # the worker (and with it the whole deploy stack) down
-                print(
-                    f"skyline worker: stats port {stats_port} unavailable "
-                    f"({e}); continuing without /stats",
-                    file=sys.stderr,
-                )
+                self._wal.flush(force=True)
+            # WAL-tailing read replicas (serve/replica.py): each gets its own
+            # SnapshotStore + ring + HTTP port, bootstraps from the newest
+            # barrier in the WAL and live-tails publish deltas. In-process
+            # spawn is the embedded/test mode; production runs them as separate
+            # processes (--replica-of) so an engine death leaves them serving.
+            self.replicas = []
+            if replicas:
+                if resilience is None or self._snap_store is None:
+                    raise ValueError(
+                        "replicas require resilience (--checkpoint-dir) and the "
+                        "serve plane (--serve)"
+                    )
+                from skyline_tpu.serve.replica import SkylineReplica
+
+                # in-process replicas share the worker's hub for the labeled
+                # replica families, the worker's ops journal, and see the
+                # primary head directly for replica_lag_versions
+                store = self._snap_store
+                for i in range(int(replicas)):
+                    self.replicas.append(
+                        SkylineReplica(
+                            self._wal_dir,
+                            port=0,
+                            serve_config=serve_config,
+                            replica_id=f"replica-{i}",
+                            telemetry=self.telemetry,
+                            opslog=self._opslog,
+                            primary_head_cb=lambda s=store: s.head_version,
+                        )
+                    )
+            self.stats_server = None
+            if stats_port is not None:
+                from skyline_tpu.metrics.httpstats import StatsServer
+
+                try:
+                    self.stats_server = StatsServer(
+                        self.stats, stats_port, telemetry=self.telemetry
+                    )
+                except OSError as e:
+                    # observability is optional: a port conflict must not take
+                    # the worker (and with it the whole deploy stack) down
+                    print(
+                        f"skyline worker: stats port {stats_port} unavailable "
+                        f"({e}); continuing without /stats",
+                        file=sys.stderr,
+                    )
 
     def stats(self) -> dict:
         """Engine counters + worker I/O counters (served by /stats)."""
@@ -1083,14 +1085,15 @@ class SkylineWorker:
                 # thread, after bus triggers — the engine stays single-owner
                 self._inject_serve_queries()
             self.engine.check_timeouts()
-        results = self.engine.poll_results()
-        if self._serve_bridge is not None:
-            # serve-plane results return to their HTTP waiters, not the bus
-            results = self._serve_bridge.fulfill(results)
-        for result in results:
-            self.bus.produce(self.output_topic, format_result(result))
-            self.results_emitted += 1
-            self._report_phases()
+        with self.tracer.phase("publish/produce"):
+            results = self.engine.poll_results()
+            if self._serve_bridge is not None:
+                # serve-plane results return to their HTTP waiters, not the bus
+                results = self._serve_bridge.fulfill(results)
+            for result in results:
+                self.bus.produce(self.output_topic, format_result(result))
+                self.results_emitted += 1
+                self._report_phases()
         work = total_lines + len(triggers)
         if work and self._wal is not None:
             # the step's durability point: positions commit (and, under the

@@ -1,4 +1,4 @@
-"""Per-phase tracing: device/host/transfer time accounting.
+"""Per-phase tracing: host time per named phase, on the profiler's clock.
 
 The reference's tracing is manual nanoTime deltas around BNL work plus the
 aggregator's ingestion/local/global decomposition, surfaced as a product
@@ -7,68 +7,176 @@ named phase timers the engine/worker/bench can nest, with the same
 "breakdown is a feature" stance: ``report()`` returns totals suitable for
 logging or embedding in results.
 
-Device timing caveat: JAX dispatch is async; a phase that should count
-device time must close over ``block_until_ready`` (use ``device_phase``) or
-the time lands in whichever phase later forces the sync.
+Every phase also belongs to one layer (``LAYERS``). While a JAX profiler
+capture is on (``jax.profiler.trace``, or a capture of a
+``jax.profiler.start_server`` port), each phase entry is written into the
+capture as a ``TraceAnnotation`` of the same name with a ``layer`` stat, on
+the host plane beside the device ops, so every device idle gap can be read
+against the host phase open in it. Off a capture a phase costs one
+``TraceMe.is_enabled()`` check more than its two clock reads.
+
+Device timing caveat: JAX dispatch is async, so a phase that launches
+device work times the enqueue; the device time lands in whichever later
+phase blocks on the result (the ``…_sync`` / ``…_transfer`` phases). The
+device side of a window is read from the capture's device plane.
 """
 
 from __future__ import annotations
 
+import threading
 import time
 from collections import defaultdict
-from contextlib import contextmanager
+
+from jax.profiler import TraceAnnotation
+
+_capturing = TraceAnnotation.is_enabled
+
+# Phase name -> layer. A key ending in "/" covers every phase whose name
+# starts with it; an exact name wins over its prefix. Phase names are read
+# by /stats ``phase_breakdown_ms`` and the worker's stderr breakdown, so
+# they keep their spelling; a phase the table does not cover is a test
+# failure (tests/test_tracing.py), never a silent "other".
+LAYERS: dict[str, str] = {
+    "worker/poll": "transport",
+    "worker/parse": "parse",
+    "ingest/": "ingest",
+    "partition_ids": "ingest",
+    "route": "ingest",
+    "flush/": "flush",
+    "slide/": "flush",
+    "global_merge": "merge",
+    "global_merge/": "merge",
+    "query/": "merge",
+    "publish": "publish",
+    "publish/": "publish",
+    "audit/": "audit",
+    "worker/build": "build",
+    # containers: what is left in them once their children are taken out
+    # is the worker loop's own glue
+    "worker/ingest": "worker",
+    "worker/query": "worker",
+}
+
+
+def layer_of(name: str) -> str:
+    """The layer of phase ``name``; ``KeyError`` for a name ``LAYERS``
+    does not cover."""
+    layer = LAYERS.get(name)
+    if layer is not None:
+        return layer
+    head, sep, _ = name.partition("/")
+    if sep and head + sep in LAYERS:
+        return LAYERS[head + sep]
+    raise KeyError(f"phase {name!r} has no layer in metrics.tracing.LAYERS")
+
+
+class LayerClock:
+    """Self time per layer of the phases entered while a profiler capture
+    is on, over every tracer in the process: a phase entry's duration less
+    the part its child phases cover, so each instant counts once, for the
+    innermost phase open on its thread. It is the split a reduction of the
+    capture's host plane gives, readable without parsing the capture."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._ns: dict[str, int] = defaultdict(int)  # guarded-by: self._lock
+        self._open = threading.local()  # innermost captured phase per thread
+
+    def _enter(self, ph: "_Phase") -> None:
+        ph._parent = getattr(self._open, "top", None)
+        ph._child_ns = 0
+        self._open.top = ph
+
+    def _leave(self, ph: "_Phase", layer: str) -> None:
+        dur = ph.t1 - ph.t0
+        self._open.top = ph._parent
+        if ph._parent is not None:
+            ph._parent._child_ns += dur
+        with self._lock:
+            self._ns[layer] += dur - ph._child_ns
+
+    def self_ms(self) -> dict[str, float]:
+        with self._lock:
+            return {k: v / 1e6 for k, v in sorted(self._ns.items())}
+
+    def reset(self) -> None:
+        with self._lock:
+            self._ns.clear()
+
+
+CAPTURED = LayerClock()
+
+
+class _Phase:
+    """One entry of a phase: timed once, the time going to its tracer's
+    totals, to a span ring when given one (``spans.record(span, ...)``
+    with ``trace_id``/``tid``/``args``, which the caller may still set
+    inside the block), and to the profiler capture when one is on."""
+
+    __slots__ = (
+        "_tracer", "name", "_spans", "span", "trace_id", "tid", "args",
+        "t0", "t1", "_ann", "_parent", "_child_ns",
+    )
+
+    def __init__(self, tracer, name, spans=None, span=None, trace_id=None,
+                 tid=0, args=None):
+        self._tracer = tracer
+        self.name = name
+        self._spans = spans
+        self.span = span
+        self.trace_id = trace_id
+        self.tid = tid
+        self.args = args
+        self._ann = None
+
+    @property
+    def ms(self) -> float:
+        return (self.t1 - self.t0) / 1e6
+
+    def __enter__(self) -> "_Phase":
+        if _capturing():
+            ann = TraceAnnotation(self.name, layer=layer_of(self.name))
+            ann.__enter__()
+            self._ann = ann
+            CAPTURED._enter(self)
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = t1 = time.perf_counter_ns()
+        tr = self._tracer
+        if tr is not None:
+            tr._total_ns[self.name] += t1 - self.t0
+            tr._count[self.name] += 1
+        if self._spans is not None:
+            self._spans.record(
+                self.span, self.t0, t1, trace_id=self.trace_id, tid=self.tid,
+                args=self.args,
+            )
+        if self._ann is not None:
+            CAPTURED._leave(self, layer_of(self.name))
+            self._ann.__exit__(*exc)
 
 
 class Tracer:
-    """Named phase timers.
+    """Named phase timers (host wall per phase name, nested phases
+    overlapping their parents, like the reference's ingestion = wall -
+    local arithmetic)."""
 
-    ``sync_device=True`` (default) makes ``device_phase`` block on its
-    arrays, attributing async device work to the phase that launched it —
-    the honest-profiling mode. ``sync_device=False`` records dispatch wall
-    only, leaving the device pipeline undisturbed (device time then lands
-    in whichever later phase forces the sync, e.g. the snapshot transfer).
-    """
-
-    def __init__(self, sync_device: bool = True):
-        self.sync_device = sync_device
+    def __init__(self):
         self._total_ns: dict[str, int] = defaultdict(int)
         self._count: dict[str, int] = defaultdict(int)
-        self._stack: list[str] = []
 
-    @contextmanager
-    def phase(self, name: str):
-        """Accumulate host wall time under ``name`` (exclusive of nothing —
-        nested phases overlap their parents by design, like the reference's
-        ingestion = wall - local arithmetic)."""
-        t0 = time.perf_counter_ns()
-        self._stack.append(name)
-        try:
-            yield
-        finally:
-            self._stack.pop()
-            self._total_ns[name] += time.perf_counter_ns() - t0
-            self._count[name] += 1
+    def phase(self, name: str) -> _Phase:
+        """``with tracer.phase(name):`` accumulates host wall under ``name``."""
+        return _Phase(self, name)
 
-    @contextmanager
-    def device_phase(self, name: str, *arrays_to_sync):
-        """Like ``phase`` but blocks on the given jax arrays before closing,
-        so async-dispatched device work is attributed here."""
-        import jax
-
-        t0 = time.perf_counter_ns()
-        self._stack.append(name)
-        try:
-            yield
-        finally:
-            self._stack.pop()
-            if arrays_to_sync and self.sync_device:
-                jax.block_until_ready(arrays_to_sync)
-            self._total_ns[name] += time.perf_counter_ns() - t0
-            self._count[name] += 1
-
-    def add_ns(self, name: str, ns: int) -> None:
-        self._total_ns[name] += ns
-        self._count[name] += 1
+    def span_phase(self, name: str, spans, span: str, trace_id=None, tid=0,
+                   args=None) -> _Phase:
+        """``phase(name)`` whose one timing also lands in the span ring
+        ``spans`` (a ``telemetry.spans.SpanRecorder``, or None for none) as
+        span ``span``: one clock pair per boundary for both."""
+        return _Phase(self, name, spans, span, trace_id, tid, args)
 
     def report(self) -> dict[str, dict[str, float]]:
         return {
@@ -85,22 +193,31 @@ class Tracer:
         self._count.clear()
 
 
+class _NullPhase:
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullPhase":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NULL_PHASE = _NullPhase()
+
+
 class _NullTracer:
     """Zero-overhead stand-in so hot paths can call ``tracer.phase(...)``
-    unconditionally; ``SkylineEngine``/``PartitionSet`` default to this."""
+    unconditionally; ``SkylineEngine``/``PartitionSet`` default to this.
+    A ``span_phase`` still times its boundary (the engine's clock
+    discipline and the span ring read it), outside any totals."""
 
-    sync_device = False
+    def phase(self, name: str) -> _NullPhase:
+        return _NULL_PHASE
 
-    @contextmanager
-    def phase(self, name: str):
-        yield
-
-    @contextmanager
-    def device_phase(self, name: str, *arrays_to_sync):
-        yield
-
-    def add_ns(self, name: str, ns: int) -> None:
-        pass
+    def span_phase(self, name: str, spans, span: str, trace_id=None, tid=0,
+                   args=None) -> _Phase:
+        return _Phase(None, name, spans, span, trace_id, tid, args)
 
     def report(self) -> dict:
         return {}

@@ -588,11 +588,13 @@ class PartitionSet:
             return False
         if self.flush_policy == "overlap":
             if self.pending_rows_total >= self.overlap_rows:
-                self.flush_all(tighten=False)
+                with self.tracer.phase("flush/auto"):
+                    self.flush_all(tighten=False)
                 return True
             return False
         if int(self._pending_rows.max()) >= self.buffer_size:
-            self.flush_all()
+            with self.tracer.phase("flush/auto"):
+                self.flush_all()
             return True
         return False
 
@@ -714,7 +716,8 @@ class PartitionSet:
                 # about to grow: tighten the bounds with ONE real count sync
                 # (growth events are log-bounded, so steady-state flushes
                 # stay fully async)
-                self._count_ub = np.asarray(self._count_dev, dtype=np.int64)
+                with self.tracer.phase("flush/count_sync"):
+                    self._count_ub = np.asarray(self._count_dev, dtype=np.int64)
                 grow = _grow_bucket()
             out_cap = max(self._cap, grow)
             variant = (
@@ -764,10 +767,6 @@ class PartitionSet:
                     )
                 if mp:
                     self._accum_resolved(res)
-                if self.tracer.sync_device:
-                    # profiling mode: attribute the async kernel here instead
-                    # of at whichever later phase forces the sync.
-                    np.asarray(self._count_dev)
             self._cap = out_cap
             self._count_ub = np.minimum(out_cap, self._count_ub + widths)
             for s in range(rnd + 1, min(rnd + 1 + depth, n_rounds)):
@@ -814,15 +813,15 @@ class PartitionSet:
             batch_dev, bvalid_dev, widths = staged.pop(rnd)
             if len(prev) >= 2:
                 c2, w1 = prev[-2][0], prev[-1][1]
-                self._count_ub = np.minimum(
-                    self._count_ub,
-                    np.asarray(c2, dtype=np.int64) + w1,
-                )
+                with self.tracer.phase("flush/count_sync"):
+                    c2 = np.asarray(c2, dtype=np.int64)
+                self._count_ub = np.minimum(self._count_ub, c2 + w1)
             # the SFS append writes a full B-row block at offset count, so
             # capacity must cover count + B for every partition
             need = int(self._count_ub.max()) + B
             if need > self._cap:
-                self._count_ub = np.asarray(counts, dtype=np.int64)
+                with self.tracer.phase("flush/count_sync"):
+                    self._count_ub = np.asarray(counts, dtype=np.int64)
                 need = int(self._count_ub.max()) + B
                 if need > self._cap:
                     self._grow_cap(_next_pow2(need))
@@ -849,8 +848,6 @@ class PartitionSet:
                     )
                 if mp:
                     self._accum_resolved(res)
-                if self.tracer.sync_device:
-                    np.asarray(counts)
             prev.append((counts, widths))
             self._count_ub = np.minimum(self._cap, self._count_ub + widths)
             for s in range(rnd + 1, min(rnd + 1 + depth, n_rounds)):
@@ -906,7 +903,7 @@ class PartitionSet:
         if not int(self._count_ub.max()):
             counts_host = np.zeros(self.num_partitions, dtype=np.int64)
         else:
-            counts_host = self.sky_counts().astype(np.int64)
+            counts_host = self.sky_counts("flush/count_sync").astype(np.int64)
         mp = mixed_precision_enabled()
         row_counts = np.array([r.shape[0] for r in rows], dtype=np.int64)
 
@@ -942,12 +939,13 @@ class PartitionSet:
                 off = 0
                 while off < rp.shape[0]:
                     if len(prev) >= 2:
-                        c2, w1 = prev[-2][0], prev[-1][1]
-                        ub_p = min(ub_p, int(c2) + w1)
+                        with self.tracer.phase("flush/count_sync"):
+                            c2, w1 = int(prev[-2][0]), prev[-1][1]
+                        ub_p = min(ub_p, c2 + w1)
                         # escalate once survival proves high: a probe
                         # round keeps <= B survivors, so compare against
                         # half a block (uniform keeps ~1% and never trips)
-                        if B < B_big and int(c2) > B // 2:
+                        if B < B_big and c2 > B // 2:
                             B = B_big
                     if ub_p + B > cap_p:
                         # tighten with one exact count sync (a blocking
@@ -956,7 +954,8 @@ class PartitionSet:
                         # to exactly ub+B would leave cap in a band this
                         # check re-enters every round, paying a pipeline
                         # stall per round instead of one per doubling
-                        ub_p = min(ub_p, int(cnt_p))
+                        with self.tracer.phase("flush/count_sync"):
+                            ub_p = min(ub_p, int(cnt_p))
                         if ub_p + 2 * B > cap_p:
                             cap_p = _next_pow2(ub_p + 2 * B)
                             sky_p = self._pad_sky_rows(sky_p, cap_p)
@@ -978,8 +977,6 @@ class PartitionSet:
                         )
                         if mp:
                             self._accum_resolved(res)
-                        if self.tracer.sync_device:
-                            np.asarray(cnt_p)
                     prev.append((cnt_p, w))
                     ub_p = min(cap_p, ub_p + w)
                     off += w
@@ -1006,7 +1003,7 @@ class PartitionSet:
         elif not tighten:
             counts_host = self._count_ub.copy()
         else:
-            counts_host = self.sky_counts().astype(np.int64)
+            counts_host = self.sky_counts("flush/count_sync").astype(np.int64)
         mp = mixed_precision_enabled()
         widths = np.diff(bounds)
         # blocks sliced from the sorted window must fit its SORT_TAIL pad
@@ -1019,7 +1016,7 @@ class PartitionSet:
             # sync ONLY now (the bounds otherwise ratchet up with rows
             # streamed and capacity would track the stream, not the
             # skyline — the same on-demand fallback _sfs_vmapped_dev uses)
-            counts_host = self.sky_counts().astype(np.int64)
+            counts_host = self.sky_counts("flush/count_sync").astype(np.int64)
             need0 = int(counts_host.max()) + B_max
         if need0 > self._cap:
             self._grow_cap(_next_pow2(need0))
@@ -1039,12 +1036,14 @@ class PartitionSet:
                 off = lo
                 while off < hi:
                     if len(prev) >= 2:
-                        c2, w1 = prev[-2][0], prev[-1][1]
-                        ub_p = min(ub_p, int(c2) + w1)
-                        if B < B_big and int(c2) > B // 2:
+                        with self.tracer.phase("flush/count_sync"):
+                            c2, w1 = int(prev[-2][0]), prev[-1][1]
+                        ub_p = min(ub_p, c2 + w1)
+                        if B < B_big and c2 > B // 2:
                             B = B_big
                     if ub_p + B > cap_p:
-                        ub_p = min(ub_p, int(cnt_p))
+                        with self.tracer.phase("flush/count_sync"):
+                            ub_p = min(ub_p, int(cnt_p))
                         if ub_p + 2 * B > cap_p:
                             cap_p = _next_pow2(ub_p + 2 * B)
                             sky_p = self._pad_sky_rows(sky_p, cap_p)
@@ -1068,8 +1067,6 @@ class PartitionSet:
                             )
                             if mp:
                                 self._accum_resolved(res)
-                        if self.tracer.sync_device:
-                            np.asarray(cnt_p)
                     prev.append((cnt_p, w))
                     ub_p = min(cap_p, ub_p + w)
                     off += w
@@ -1365,7 +1362,7 @@ class PartitionSet:
         if not int(self._count_ub.max()):
             counts_host = np.zeros(self.num_partitions, dtype=np.int64)
         else:
-            counts_host = self.sky_counts().astype(np.int64)
+            counts_host = self.sky_counts("flush/count_sync").astype(np.int64)
         new_skies = []
         new_counts = []
         for p in range(self.num_partitions):
@@ -1413,7 +1410,7 @@ class PartitionSet:
         if not int(self._count_ub.max()):
             counts_host = np.zeros(self.num_partitions, dtype=np.int64)
         else:
-            counts_host = self.sky_counts().astype(np.int64)
+            counts_host = self.sky_counts("flush/count_sync").astype(np.int64)
         new_skies = []
         new_counts = []
         for p in range(self.num_partitions):
@@ -1454,7 +1451,11 @@ class PartitionSet:
         """Non-empty initial state needs exact old counts for the final
         old-vs-new cleanup pass (one sync; fresh windows skip it)."""
         had_old = bool((self._count_ub > 0).any())
-        old_counts = self.sky_counts().astype(np.int32) if had_old else None
+        old_counts = (
+            self.sky_counts("flush/count_sync").astype(np.int32)
+            if had_old
+            else None
+        )
         if had_old and not int(old_counts.max()):
             had_old = False
         return had_old, old_counts
@@ -1502,8 +1503,6 @@ class PartitionSet:
                         self.sky, counts, jnp.asarray(old_counts),
                         old_active, active,
                     )
-                if self.tracer.sync_device:
-                    np.asarray(counts)
         self._count_dev = counts
         # validity is a pure function of counts under append-only state
         self.sky_valid = jnp.arange(self._cap)[None, :] < counts[:, None]
@@ -1658,6 +1657,7 @@ class PartitionSet:
                 self.num_partitions,
                 dw.SORT_TAIL,
             )
+        with self.tracer.phase("flush/bounds_sync"):
             bounds = np.asarray(bounds_dev, dtype=np.int64)
         self._bump_epoch(np.diff(bounds) > 0)
         self._dev_rows = 0
@@ -1735,13 +1735,13 @@ class PartitionSet:
             w = np.clip(hi - offs, 0, B)
             if len(prev) >= 2:
                 c2, w1 = prev[-2][0], prev[-1][1]
-                self._count_ub = np.minimum(
-                    self._count_ub,
-                    np.asarray(c2, dtype=np.int64) + w1,
-                )
+                with self.tracer.phase("flush/count_sync"):
+                    c2 = np.asarray(c2, dtype=np.int64)
+                self._count_ub = np.minimum(self._count_ub, c2 + w1)
             need = int(self._count_ub.max()) + B
             if need > self._cap:
-                self._count_ub = np.asarray(counts, dtype=np.int64)
+                with self.tracer.phase("flush/count_sync"):
+                    self._count_ub = np.asarray(counts, dtype=np.int64)
                 need = int(self._count_ub.max()) + B
                 if need > self._cap:
                     self._grow_cap(_next_pow2(need))
@@ -1766,8 +1766,6 @@ class PartitionSet:
                     )
                     if mp:
                         self._accum_resolved(res)
-                if self.tracer.sync_device:
-                    np.asarray(counts)
             prev.append((counts, w))
             self._count_ub = np.minimum(self._cap, self._count_ub + w)
         self._count_dev = counts
@@ -2254,11 +2252,12 @@ class PartitionSet:
                 c["pts_host"] = np.asarray(c["pts_dev"])[: c["g"]].copy()
         return c["pts_host"].copy()
 
-    def sky_counts(self) -> np.ndarray:
+    def sky_counts(self, phase: str = "query/count_sync") -> np.ndarray:
         """Exact survivor counts (P,) — one device sync (cached until the
-        next flush)."""
+        next flush), timed under ``phase`` (the flush's own reads pass
+        ``flush/count_sync``)."""
         if self._counts_cache is None:
-            with self.tracer.phase("query/count_sync"):
+            with self.tracer.phase(phase):
                 self._counts_cache = np.asarray(self._count_dev, dtype=np.int64)
             self._count_ub = self._counts_cache.copy()
         self._tighten_pending = False
@@ -2300,6 +2299,9 @@ class PartitionSet:
         host arrays. One bulk device→host transfer (the ``_host_sky``
         cache) — no flush, no merge, no epoch advance, so capturing for a
         shadow check never perturbs the state being checked."""
+        if self._host_cache is None:
+            with self.tracer.phase("audit/state_sync"):
+                self._host_cache = np.asarray(self.sky)
         skies = [self.skyline_host(p) for p in range(self.num_partitions)]
         pendings = [
             self.pending_rows_of(p) for p in range(self.num_partitions)

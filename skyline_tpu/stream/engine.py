@@ -342,18 +342,13 @@ class SkylineEngine:
         wire format carries no timestamps, so callers typically stamp the
         poll wall time (a processing-time proxy; RUNBOOK §2j).
         """
-        tel = self.telemetry
-        if tel is None:
-            return self._process_records(ids, values, now_ms, event_ms)
-        t0 = time.perf_counter_ns()
-        try:
-            return self._process_records(ids, values, now_ms, event_ms)
-        finally:
-            end = time.perf_counter_ns()
-            tel.histogram("ingest_batch_ms").observe((end - t0) / 1e6)
-            tel.spans.record(
-                "ingest", t0, end, args={"rows": int(values.shape[0])}
-            )
+        with self.tracer.span_phase(
+            "ingest/batch", self._spans, "ingest",
+            args={"rows": int(values.shape[0])},
+        ) as ph:
+            self._process_records(ids, values, now_ms, event_ms)
+        if self.telemetry is not None:
+            self.telemetry.histogram("ingest_batch_ms").observe(ph.ms)
 
     def _process_records(
         self,
@@ -463,6 +458,11 @@ class SkylineEngine:
         # harvest here; one that hasn't stays in flight (never block ingest)
         self._harvest_inflight(block=False)
 
+    @property
+    def _spans(self):
+        """The hub's span ring (``/trace``), or None without telemetry."""
+        return self.telemetry.spans if self.telemetry is not None else None
+
     def _note_flush(self) -> None:
         """Advance the freshness flush stage once NO ingested rows remain
         host-pending — lazy/overlap policies may leave rows buffered past a
@@ -558,16 +558,15 @@ class SkylineEngine:
         (local > total, ingestion clamped). So the snapshot's own wall is
         added to the caller's clock before recording the arrival."""
         part = self.partitions[p]
-        t0 = time.perf_counter_ns()
-        local = part.snapshot()
-        self._note_flush()
-        t1 = time.perf_counter_ns()
-        if self.telemetry is not None:
-            self.telemetry.spans.record(
-                "local", t0, t1, trace_id=q.trace_id, tid=p,
-                args={"rows": int(local.shape[0])},
-            )
-        arrival_ms = now_ms + (t1 - t0) / 1e6
+        args = {}
+        with self.tracer.span_phase(
+            "flush/local", self._spans, "local", trace_id=q.trace_id, tid=p,
+            args=args,
+        ) as ph:
+            local = part.snapshot()
+            self._note_flush()
+            args["rows"] = int(local.shape[0])
+        arrival_ms = now_ms + ph.ms
         start = part.start_time_ms if part.start_time_ms is not None else now_ms
         q.partials[p] = local
         q.local_sizes[p] = local.shape[0]
@@ -587,8 +586,11 @@ class SkylineEngine:
         ``now_ms`` continues the caller's clock; the merge's own device time
         is added on top so global_processing_time_ms stays real even under an
         injected clock."""
-        merge_t0 = time.perf_counter_ns()
-        with self.tracer.phase("global_merge"):
+        args = {}
+        with self.tracer.span_phase(
+            "global_merge", self._spans, "merge", trace_id=q.trace_id,
+            args=args,
+        ) as ph:
             pids_order = sorted(q.partials)
             stacked = [q.partials[p] for p in pids_order]
             origins = np.concatenate(
@@ -610,20 +612,15 @@ class SkylineEngine:
             else:
                 keep = skyline_keep_np(union)
             global_sky = union[keep]
-        survivors_per_pid = np.bincount(
-            origins[keep], minlength=self.config.num_partitions
-        )
-
-        if self.freshness is not None:
-            self.freshness.on_merge()
-        merge_end_ns = time.perf_counter_ns()
-        merge_ms = (merge_end_ns - merge_t0) / 1e6
-        if self.telemetry is not None:
-            self.telemetry.spans.record(
-                "merge", merge_t0, merge_end_ns, trace_id=q.trace_id,
-                args={"union_rows": int(union.shape[0]),
-                      "skyline_size": int(global_sky.shape[0])},
+            survivors_per_pid = np.bincount(
+                origins[keep], minlength=self.config.num_partitions
             )
+            if self.freshness is not None:
+                self.freshness.on_merge()
+            args["union_rows"] = int(union.shape[0])
+            args["skyline_size"] = int(global_sky.shape[0])
+        merge_ms = ph.ms
+        if self.telemetry is not None:
             self.telemetry.histogram("global_merge_ms").observe(merge_ms)
         now = now_ms + merge_ms
         job_start = min(q.start_times.values()) if q.start_times else now
@@ -646,18 +643,19 @@ class SkylineEngine:
 
         if self.snapshots is not None:
             self._publish_snapshot(global_sky, q)
-        self._emit_result(
-            q,
-            skyline_size=int(global_sky.shape[0]),
-            optimality=float(optimality),
-            ingestion=ingestion,
-            local_ms=local_ms,
-            global_ms=global_ms,
-            total_ms=total_ms,
-            latency_ms=latency_ms,
-            points=global_sky if self.config.emit_skyline_points else None,
-            partial_missing=partial_missing,
-        )
+        with self.tracer.phase("publish/emit"):
+            self._emit_result(
+                q,
+                skyline_size=int(global_sky.shape[0]),
+                optimality=float(optimality),
+                ingestion=ingestion,
+                local_ms=local_ms,
+                global_ms=global_ms,
+                total_ms=total_ms,
+                latency_ms=latency_ms,
+                points=global_sky if self.config.emit_skyline_points else None,
+                partial_missing=partial_missing,
+            )
 
     def _publish_snapshot(
         self, points, q: _QueryState, source_key=None, degraded=None
@@ -684,14 +682,10 @@ class SkylineEngine:
             # the merged window's newest event time becomes the snapshot's
             # published watermark (monotone; None until any event stamped)
             meta["event_wm_ms"] = self.freshness.on_publish()
-        if self.telemetry is None:
-            self.snapshots.publish(points, **meta)
-            return
-        t0 = time.perf_counter_ns()
-        snap = self.snapshots.publish(points, **meta)
-        self.telemetry.spans.record(
-            "publish", t0, time.perf_counter_ns(), trace_id=q.trace_id
-        )
+        with self.tracer.span_phase(
+            "publish", self._spans, "publish", trace_id=q.trace_id
+        ):
+            snap = self.snapshots.publish(points, **meta)
         if q.plan is not None and snap is not None:
             # a deduped publish returns the EXISTING snapshot — the plan
             # still records which version its answer's bytes live under
@@ -881,19 +875,14 @@ class SkylineEngine:
         Timing decomposition follows the same clock discipline as
         _answer/_finalize: the flush wall advances the arrival clock (local
         phase); the merge wall rides on top (global phase)."""
-        tel = self.telemetry
-        t0 = time.perf_counter_ns()
-        self.pset.flush_all()
-        self._note_flush()
-        flush_end_ns = time.perf_counter_ns()
-        flush_wall_ms = (flush_end_ns - t0) / 1e6
-        if tel is not None:
-            # one stacked launch covers every partition's local skyline
-            tel.spans.record(
-                "local", t0, flush_end_ns, trace_id=q.trace_id,
-                args={"partitions": self.config.num_partitions},
-            )
-        t1 = time.perf_counter_ns()
+        # one stacked launch covers every partition's local skyline
+        with self.tracer.span_phase(
+            "flush/local", self._spans, "local", trace_id=q.trace_id,
+            args={"partitions": self.config.num_partitions},
+        ) as ph:
+            self.pset.flush_all()
+            self._note_flush()
+        flush_wall_ms = ph.ms
         # an attached snapshot store needs the materialized points even when
         # the result JSON omits them — the snapshot IS the serving read path
         want_points = (
@@ -907,23 +896,26 @@ class SkylineEngine:
             # process_records once the stats bytes land), where the phase
             # records only the residual harvest time instead of the full
             # merge wall.
-            handle = self.pset.global_merge_launch(emit_points=want_points)
-            launch_ms = (time.perf_counter_ns() - t1) / 1e6
-            self._inflight_merge = (q, handle, now_ms, flush_wall_ms, launch_ms)
+            with self.tracer.span_phase(
+                "global_merge/launch", None, None
+            ) as ph:
+                handle = self.pset.global_merge_launch(emit_points=want_points)
+            self._inflight_merge = (q, handle, now_ms, flush_wall_ms, ph.ms)
             return
-        counts, surv, g, pts = self.pset.global_merge_stats(
-            emit_points=want_points
-        )
-        if self.freshness is not None:
-            self.freshness.on_merge()
-        merge_end_ns = time.perf_counter_ns()
-        merge_ms = (merge_end_ns - t1) / 1e6
-        if tel is not None:
-            tel.spans.record(
-                "merge", t1, merge_end_ns, trace_id=q.trace_id,
-                args={"skyline_size": int(g)},
+        args = {}
+        with self.tracer.span_phase(
+            "global_merge", self._spans, "merge", trace_id=q.trace_id,
+            args=args,
+        ) as ph:
+            counts, surv, g, pts = self.pset.global_merge_stats(
+                emit_points=want_points
             )
-            tel.histogram("global_merge_ms").observe(merge_ms)
+            if self.freshness is not None:
+                self.freshness.on_merge()
+            args["skyline_size"] = int(g)
+        merge_ms = ph.ms
+        if self.telemetry is not None:
+            self.telemetry.histogram("global_merge_ms").observe(merge_ms)
         self._emit_device_result(
             q, now_ms, flush_wall_ms, merge_ms, counts, surv, g, pts,
             source_key=self.pset.epoch_key,
@@ -940,20 +932,21 @@ class SkylineEngine:
         if not block and not handle.ready():
             return False
         self._inflight_merge = None
-        h0 = time.perf_counter_ns()
-        counts, surv, g, pts = self.pset.global_merge_harvest(handle)
-        if self.freshness is not None:
-            self.freshness.on_merge()
-        h1 = time.perf_counter_ns()
+        args = {}
+        with self.tracer.span_phase(
+            "global_merge/harvest", self._spans, "merge", trace_id=q.trace_id,
+            args=args,
+        ) as ph:
+            counts, surv, g, pts = self.pset.global_merge_harvest(handle)
+            if self.freshness is not None:
+                self.freshness.on_merge()
+            args["skyline_size"] = int(g)
+            args["overlapped"] = True
         # the query's merge cost = launch dispatch + harvest sync; the
         # in-flight span in between ran under ingest, so charging it here
         # would double-count the overlap the split exists to buy
-        merge_ms = launch_ms + (h1 - h0) / 1e6
+        merge_ms = launch_ms + ph.ms
         if self.telemetry is not None:
-            self.telemetry.spans.record(
-                "merge", h0, h1, trace_id=q.trace_id,
-                args={"skyline_size": int(g), "overlapped": True},
-            )
             self.telemetry.histogram("global_merge_ms").observe(merge_ms)
         self._emit_device_result(
             q, now_ms, flush_wall_ms, merge_ms, counts, surv, g, pts,
@@ -990,18 +983,21 @@ class SkylineEngine:
         job_start = min(starts) if starts else now
         local_ms = self.pset.processing_ms
         map_wall = max(0.0, map_finish - job_start)
-        self._emit_result(
-            q,
-            skyline_size=g,
-            optimality=optimality_mean(surv, counts, self.config.num_partitions),
-            ingestion=max(0.0, map_wall - local_ms),
-            local_ms=local_ms,
-            global_ms=now - map_finish,
-            total_ms=now - job_start,
-            latency_ms=now - q.dispatch_ms,
-            points=pts if self.config.emit_skyline_points else None,
-            degraded=degraded,
-        )
+        with self.tracer.phase("publish/emit"):
+            self._emit_result(
+                q,
+                skyline_size=g,
+                optimality=optimality_mean(
+                    surv, counts, self.config.num_partitions
+                ),
+                ingestion=max(0.0, map_wall - local_ms),
+                local_ms=local_ms,
+                global_ms=now - map_finish,
+                total_ms=now - job_start,
+                latency_ms=now - q.dispatch_ms,
+                points=pts if self.config.emit_skyline_points else None,
+                degraded=degraded,
+            )
 
     # -- failure detection -------------------------------------------------
 

@@ -16,7 +16,7 @@ site's existing ``flush/merge_kernel`` tracer phase with
 per dispatch, nothing inside jit. Because the profiler times the same
 region the phase tracer does, the /profile endpoint can attribute the
 phase total to named signatures (the ISSUE-8 >=90% acceptance bar holds by
-construction, modulo the tracer's own sync toggle).
+construction).
 
 Kernel slices also land in the shared ``SpanRecorder`` ring (``kernel/<
 variant>`` spans, tid 2), so the Chrome-trace export shows which variant
