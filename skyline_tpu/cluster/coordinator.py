@@ -349,6 +349,9 @@ class ClusterPartitionSet:
             "prefilter_seen": seen,
             "prefilter_dropped": dropped,
             "prefilter_drop_fraction": (dropped / seen) if seen else 0.0,
+            "prefilter_packed_seen": sum(
+                d["prefilter_packed_seen"] for d in docs
+            ),
             "bf16_resolved": sum(d["bf16_resolved"] for d in docs),
         }
 

@@ -202,8 +202,9 @@ def flush_prefilter_enabled() -> bool:
     device-computed grid summary of its resident skyline (per-dim boundary
     ladder + representative-cell codes, refreshed async at flush tails), and
     incoming batch rows whose cell is strictly dominated by a representative
-    cell are dropped on the host before any merge kernel launches — an
-    O(B·C) byte-compare pass with C ≪ S. Sound by construction: a cell-level
+    cell are dropped on the host before any merge kernel launches — a binary
+    search per dim to code each row, then (up to 9 dims) one packed int64
+    test per representative, C ≪ S of them. Sound by construction: a cell-level
     strict dominance certificate implies strict f32 dominance (see RUNBOOK
     §2g), and a stale summary only under-drops (skyline evolution preserves
     transitive dominators). Default ON; set ``0`` for the exact-only

@@ -80,10 +80,64 @@ _PROBE_B = 8192
 # bounding the set of ingest executables.
 _CHUNK_BUCKET_MAX = 65536
 
-# Host chunk for the grid-prefilter cell coding: the (chunk, GRID_REPS, d)
-# comparison broadcast stays ~10 MB at 8D instead of scaling with the
+# Host chunk for the grid prefilter: the broadcast path's (chunk,
+# GRID_REPS, d) comparison stays ~10 MB at 10D instead of scaling with the
 # whole pending window.
 _PREFILTER_CHUNK = 16384
+
+# Packed-field grid certificate: a row's codes ``vy + 64`` and a
+# representative's codes ``u + 1`` sit in 7-bit fields of one int64, so
+# each field of the difference is ``vy - u + 63`` in [30, 95] (codes lie in
+# [-1, GRID_BINS] and [0, GRID_BINS]): no field borrows from the next, and
+# its bit 6 is set iff ``u < vy``. Nine fields fill 63 bits.
+_FIELD_BITS = 7
+_PACKED_MAX_DIMS = 63 // _FIELD_BITS
+
+
+def _field_shifts(d: int) -> np.ndarray:
+    return np.arange(d, dtype=np.int64) * _FIELD_BITS
+
+
+def _grid_codes(b: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    """(m, d) int64 grid codes of ``rows`` against a (GRID_BINS+1, d)
+    ladder: the largest index with ``b[vy] <= y``, -1 below the ladder
+    and for NaN. On a strictly increasing column the right-side binary
+    search gives exactly ``#(b <= y) - 1``; on a column validation
+    disabled it still lands in [-1, GRID_BINS], where it never meets a
+    certifying representative (those are coded GRID_BINS+1 there)."""
+    c = np.asarray(rows, np.float32)
+    vy = np.empty(c.shape, np.int64)
+    for j in range(c.shape[1]):
+        vy[:, j] = np.searchsorted(b[:, j], c[:, j], side="right")
+    vy -= 1
+    vy[np.isnan(c)] = -1  # searchsorted orders NaN above every bound
+    return vy
+
+
+def _grid_certifiers(u: np.ndarray, packed: bool) -> np.ndarray:
+    """The representatives of one partition that can certify a drop: every
+    code <= GRID_BINS, duplicates and covered codes removed (a code vector
+    at or above another in every dim certifies only rows the other already
+    does, so the drop mask is unchanged). ``packed`` returns them as
+    ``_packed_drop`` operands, (K,) int64; else as (K, d) codes."""
+    u = np.unique(u[(u <= GRID_BINS).all(axis=1)], axis=0)
+    covered = (u[None, :, :] <= u[:, None, :]).all(axis=2).sum(axis=1) > 1
+    u = u[~covered]
+    if not packed:
+        return u
+    return ((u.astype(np.int64) + 1) << _field_shifts(u.shape[1])).sum(axis=1)
+
+
+def _packed_drop(vy: np.ndarray, U: np.ndarray) -> np.ndarray:
+    """Rows whose (m, d) codes ``vy`` lie strictly above some packed
+    representative in ``U`` in every dim (see ``_FIELD_BITS``)."""
+    shifts = _field_shifts(vy.shape[1])
+    W = ((vy + 64) << shifts).sum(axis=1)
+    H = int((np.int64(64) << shifts).sum())
+    drop = np.zeros(W.shape[0], dtype=bool)
+    for Uk in U:
+        drop |= ((W - Uk) & H) == H
+    return drop
 
 
 class _MergeHandle:
@@ -333,6 +387,7 @@ class PartitionSet:
         self._grid_epoch: np.ndarray | None = None
         self.prefilter_dropped = 0
         self.prefilter_seen = 0
+        self.prefilter_packed_seen = 0
         # mixed-precision stage 2: running device scalar of bf16-resolved
         # pair counts (one tiny add per flush round, synced only on the
         # stats path) + the high-water mark already fed to the telemetry
@@ -1127,13 +1182,15 @@ class PartitionSet:
 
     def _grid_summaries(self):
         """Validated host copy of the launched grid summary, or ``None``
-        when no summary exists yet. Host-side validation disables (per
-        partition x dim) any boundary ladder f32 rounding failed to keep
-        strictly increasing — codes against a non-monotone ladder could
-        certify false dominance; a disabled dim never certifies, which
-        disables drops for its whole partition (the certificate needs
-        every dim). Empty partitions produce NaN ladders and disable
-        everything — zero drops, conservative."""
+        when no summary exists yet: ``(bounds, certifiers)``, the latter
+        one ``_grid_certifiers`` result per partition.
+        Host-side validation disables (per partition x dim) any boundary
+        ladder f32 rounding failed to keep strictly increasing — codes
+        against a non-monotone ladder could certify false dominance; a
+        disabled dim never certifies, which disables drops for its whole
+        partition (the certificate needs every dim). Empty partitions
+        produce NaN ladders and disable everything — zero drops,
+        conservative."""
         if self._grid_dev is None:
             return None
         if self._grid_host is None:
@@ -1143,14 +1200,20 @@ class PartitionSet:
                 bad = ~np.all(np.diff(bounds, axis=1) > 0, axis=1)  # (P, d)
             if bad.any():
                 ux[np.broadcast_to(bad[:, None, :], ux.shape)] = GRID_BINS + 1
-            self._grid_host = (bounds, ux)
+            packed = self.dims <= _PACKED_MAX_DIMS
+            self._grid_host = (
+                bounds, [_grid_certifiers(u, packed) for u in ux]
+            )
         return self._grid_host
 
     def _prefilter_rows(self, rows: list[np.ndarray]) -> list[np.ndarray]:
         """Stage 1 of the flush cascade: drop pending rows whose grid cell
         is strictly dominated by a representative cell of their partition's
-        resident skyline — an O(B·C) integer-compare pass before any merge
-        kernel launches (C = GRID_REPS ≪ S resident rows).
+        resident skyline, before any merge kernel launches. Rows are coded
+        by one binary search per dim (``_grid_codes``); for ``dims <=
+        _PACKED_MAX_DIMS`` each row's codes pack into one int64 and each
+        representative costs one subtract and mask (``_packed_drop``),
+        above that a broadcast compare against every representative.
 
         Soundness: a row y coded ``vy`` and a representative x coded ``ux``
         with ``ux < vy`` in EVERY dim satisfy
@@ -1172,42 +1235,42 @@ class PartitionSet:
         grid = self._grid_summaries()
         seen = int(sum(r.shape[0] for r in rows))
         dropped = 0
+        packed = self.dims <= _PACKED_MAX_DIMS
         if grid is not None and seen:
-            bounds, ux = grid
+            bounds, certifiers = grid
             with self.tracer.phase("flush/prefilter"):
                 for p, r in enumerate(rows):
                     n = r.shape[0]
-                    if n == 0:
-                        continue
-                    b = bounds[p]  # (GRID_BINS+1, d) boundary ladder
-                    u = ux[p]  # (R, d) representative cell codes
-                    if not (u <= GRID_BINS).all(axis=1).any():
+                    u = certifiers[p]
+                    if n == 0 or u.shape[0] == 0:
                         continue  # no representative can certify here
+                    b = bounds[p]  # (GRID_BINS+1, d) boundary ladder
                     keep = np.ones(n, dtype=bool)
                     any_drop = False
                     for s in range(0, n, _PREFILTER_CHUNK):
-                        c = np.asarray(
-                            r[s : s + _PREFILTER_CHUNK], np.float32
-                        )
-                        # vy = largest ladder index with bounds[vy] <= y
-                        # (NaN compares false everywhere -> vy = -1)
-                        vy = (
-                            b[None, :, :] <= c[:, None, :]
-                        ).sum(axis=1, dtype=np.int32) - 1  # (m, d)
-                        drop = np.any(
-                            np.all(u[None, :, :] < vy[:, None, :], axis=2),
-                            axis=1,
-                        )
+                        vy = _grid_codes(b, r[s : s + _PREFILTER_CHUNK])
+                        if packed:
+                            drop = _packed_drop(vy, u)
+                        else:
+                            drop = np.any(
+                                np.all(u[None, :, :] < vy[:, None, :], axis=2),
+                                axis=1,
+                            )
                         if drop.any():
-                            keep[s : s + c.shape[0]] = ~drop
+                            keep[s : s + vy.shape[0]] = ~drop
                             any_drop = True
+                    if packed:
+                        self.prefilter_packed_seen += n
                     if any_drop:
                         dropped += int(n - keep.sum())
                         rows[p] = r[keep]
         self.prefilter_seen += seen
         self.prefilter_dropped += dropped
         if seen:
-            self._fnote("flush.prefilter", seen=seen, dropped=dropped)
+            self._fnote(
+                "flush.prefilter", seen=seen, dropped=dropped,
+                path="packed" if packed else "broadcast",
+            )
         # inc 0 too: the Prometheus series must register at the first
         # prefiltered flush, not the first nonzero drop (obs_smoke asserts
         # presence right after one flush+stats round trip)
@@ -1241,6 +1304,7 @@ class PartitionSet:
             "prefilter_drop_fraction": (
                 self.prefilter_dropped / seen if seen else 0.0
             ),
+            "prefilter_packed_seen": self.prefilter_packed_seen,
             "bf16_resolved": self.bf16_resolved,
         }
 
