@@ -29,7 +29,7 @@ from collections import defaultdict
 
 from jax.profiler import TraceAnnotation
 
-_capturing = TraceAnnotation.is_enabled
+capturing = TraceAnnotation.is_enabled
 
 # Phase name -> layer. A key ending in "/" covers every phase whose name
 # starts with it; an exact name wins over its prefix. Phase names are read
@@ -75,11 +75,16 @@ class LayerClock:
     is on, over every tracer in the process: a phase entry's duration less
     the part its child phases cover, so each instant counts once, for the
     innermost phase open on its thread. It is the split a reduction of the
-    capture's host plane gives, readable without parsing the capture."""
+    capture's host plane gives, readable without parsing the capture.
+
+    Beside the times it keeps named integer counts (``count``), added only
+    while a capture is on: work the program does that a time cannot show,
+    such as the padded dominance pairs of a vmapped merge."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._ns: dict[str, int] = defaultdict(int)  # guarded-by: self._lock
+        self._counts: dict[str, int] = defaultdict(int)  # guarded-by: self._lock
         self._open = threading.local()  # innermost captured phase per thread
 
     def _enter(self, ph: "_Phase") -> None:
@@ -99,9 +104,21 @@ class LayerClock:
         with self._lock:
             return {k: v / 1e6 for k, v in sorted(self._ns.items())}
 
+    def count(self, name: str, n: int) -> None:
+        """Adds ``n`` to count ``name`` while a capture is on; off a capture
+        it is one check and nothing else."""
+        if capturing():
+            with self._lock:
+                self._counts[name] += int(n)
+
+    def counts(self) -> dict[str, int]:
+        with self._lock:
+            return dict(sorted(self._counts.items()))
+
     def reset(self) -> None:
         with self._lock:
             self._ns.clear()
+            self._counts.clear()
 
 
 CAPTURED = LayerClock()
@@ -134,7 +151,7 @@ class _Phase:
         return (self.t1 - self.t0) / 1e6
 
     def __enter__(self) -> "_Phase":
-        if _capturing():
+        if capturing():
             ann = TraceAnnotation(self.name, layer=layer_of(self.name))
             ann.__enter__()
             self._ann = ann

@@ -42,7 +42,12 @@ def mr_grid(x: jax.Array, num_partitions: int, domain_max: float) -> jax.Array:
     land on partition ids that never receive a query trigger and are silently
     dropped from results (SURVEY.md §2.1 note on J4). Here the cell id is
     folded onto partitions with a modulo so every tuple reaches a queried
-    partition; adjacent cells interleave across partitions.
+    partition. At ``d > log2(num_partitions)`` the fold makes cells ``c``
+    and ``c + num_partitions`` (and so on) share a partition: the cells that
+    differ only in the high dims. On 4-D anti-correlated data over 8
+    partitions, partition 0 takes cells 0 and 8, about 41% of the rows and
+    the whole skyline (the origin's copies), a skew the
+    ``anticorr4d-grid`` benchmark cell measures.
     """
     return (mr_grid_cell(x, domain_max) % num_partitions).astype(jnp.int32)
 

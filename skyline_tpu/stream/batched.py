@@ -31,7 +31,7 @@ from contextlib import nullcontext
 import jax.numpy as jnp
 import numpy as np
 
-from skyline_tpu.metrics.tracing import NULL_TRACER
+from skyline_tpu.metrics.tracing import CAPTURED, NULL_TRACER, capturing
 from skyline_tpu.resilience.faults import fault_point
 from skyline_tpu.ops import cascade
 from skyline_tpu.ops.dispatch import (
@@ -786,6 +786,17 @@ class PartitionSet:
                     self._cap,
                     _active_bucket(max(int(self._count_ub.max()), 1)),
                 )
+                if capturing():
+                    # every partition runs against the largest one's
+                    # prefix: the launch's pairs, and (from the host's
+                    # upper bounds, no sync) the pairs among live rows
+                    CAPTURED.count(
+                        "flush/merge_pairs", self.num_partitions * active * B
+                    )
+                    CAPTURED.count(
+                        "flush/merge_live_pairs",
+                        int((np.minimum(self._count_ub, active) * widths).sum()),
+                    )
                 if self._profiler is not None and profile_cost_enabled():
                     cost_thunk = self._merge_cost_thunk(
                         batch_dev, bvalid_dev, active, grow, mp

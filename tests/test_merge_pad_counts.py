@@ -1,0 +1,96 @@
+"""The capture clock's counts, and the incremental merge's pair counts
+under one hot partition: each vmapped round runs every partition against
+the prefix sized to the largest one's live count."""
+
+import jax
+import numpy as np
+import pytest
+
+from skybench import reference
+from skyline_tpu.metrics import tracing
+from skyline_tpu.stream import batched
+from skyline_tpu.stream.batched import PartitionSet
+
+
+@pytest.fixture
+def clock():
+    tracing.CAPTURED.reset()
+    yield tracing.CAPTURED
+    tracing.CAPTURED.reset()
+
+
+def test_counts_only_while_a_capture_is_on(clock, tmp_path):
+    clock.count("flush/merge_pairs", 7)
+    assert clock.counts() == {}
+    with jax.profiler.trace(str(tmp_path)):
+        clock.count("flush/merge_pairs", 7)
+        clock.count("flush/merge_pairs", 5)
+        clock.count("flush/merge_live_pairs", 3)
+    clock.count("flush/merge_pairs", 100)
+    assert clock.counts() == {"flush/merge_live_pairs": 3, "flush/merge_pairs": 12}
+    clock.reset()
+    assert clock.counts() == {}
+
+
+def _hot_batches(rng, n_flushes):
+    """Per flush, 4 partitions' integer rows: partition 0 takes 3,000
+    anti-correlated rows, a third of them copies of the origin (its skyline
+    grows by ~1,000 rows a flush); the others take 150 uniform rows."""
+    out = []
+    for _ in range(n_flushes):
+        hot = rng.integers(0, 100, (3000, 4))
+        hot[:, 3] = 300 - hot[:, :3].sum(axis=1)
+        hot[rng.random(3000) < 1 / 3] = 0
+        cold = [rng.integers(1, 1000, (150, 4)) for _ in range(3)]
+        out.append([hot.astype(np.float32)] + [c.astype(np.float32) for c in cold])
+    return out
+
+
+def test_hot_partition_merge_counts(clock, tmp_path, monkeypatch):
+    rng = np.random.default_rng(25)
+    P, d = 4, 4
+    pset = PartitionSet(P, d, buffer_size=1024)
+    rounds = []
+    merge = batched.merge_step_active
+
+    def recording(sky, sky_valid, batch, bvalid, active, out_active, mp=False):
+        widths = np.asarray(bvalid).sum(axis=1)
+        live = np.asarray(sky_valid)[:, :active].sum(axis=1)
+        rounds.append((batch.shape[0], active, batch.shape[1], widths, live,
+                       pset._count_ub.copy()))
+        return merge(sky, sky_valid, batch, bvalid, active, out_active, mp)
+
+    monkeypatch.setattr(batched, "merge_step_active", recording)
+    seen = [[] for _ in range(P)]
+    with jax.profiler.trace(str(tmp_path)):
+        for k, flush in enumerate(_hot_batches(rng, 5)):
+            for p, rows in enumerate(flush):
+                pset.add_batch(p, rows, max_id=k, now_ms=0.0)
+                seen[p].append(rows)
+            pset.flush_all()
+    counts = clock.counts()
+
+    assert len(rounds) > 5  # the hot partition takes extra rounds
+    assert max(r[1] for r in rounds) >= 4096  # its prefix outgrew the cold ones
+    assert counts["flush/merge_pairs"] == sum(n * a * b for n, a, b, *_ in rounds)
+    assert counts["flush/merge_live_pairs"] == sum(
+        int((np.minimum(ub, a) * w).sum()) for _, a, _, w, _, ub in rounds
+    )
+    exact_live = sum(int((np.minimum(lv, a) * w).sum()) for _, a, _, w, lv, _ in rounds)
+    assert exact_live <= counts["flush/merge_live_pairs"] <= counts["flush/merge_pairs"]
+    # the cold partitions' ~100 rows against the hot one's prefix: mostly padding
+    assert counts["flush/merge_live_pairs"] < counts["flush/merge_pairs"] / 2
+
+    for p in range(P):
+        want = reference.skyline(np.concatenate(seen[p]))
+        assert reference.multiset_gap(pset.snapshot(p), want) == 0, p
+    assert pset.snapshot(0).shape[0] > 4096
+
+
+def test_no_counts_off_a_capture(clock):
+    rng = np.random.default_rng(26)
+    pset = PartitionSet(4, 4, buffer_size=1024)
+    for p, rows in enumerate(_hot_batches(rng, 1)[0]):
+        pset.add_batch(p, rows, max_id=0, now_ms=0.0)
+    pset.flush_all()
+    assert clock.counts() == {}
