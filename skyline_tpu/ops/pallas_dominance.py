@@ -164,7 +164,7 @@ def _tile_body(d: int, mp: bool, x_ref, y_ref, v_ref, out_ref):
     jax.lax.fori_loop(0, n_row_blocks * (c // sc), block, 0)
 
 
-def _tile_sum_skip(d: int, x_ref, y_ref, v_ref):
+def _tile_sum_skip(d: int, x_ref, y_ref, v_ref, yv_ref=None):
     """Sum-bound early exit for one (R, C) tile: if the smallest coordinate
     sum among VALID dominator rows exceeds the largest victim sum, no pair in
     the tile can dominate and the compute body is skipped.
@@ -172,18 +172,59 @@ def _tile_sum_skip(d: int, x_ref, y_ref, v_ref):
     Soundness in f32: rounded addition is monotone, so ``a <= b`` per-dim
     implies ``sumf(a) <= sumf(b)`` — domination never crosses a strict sum
     gap. Strict ``>`` is required (a dominator may tie its victim's sum).
-    +inf pad victims give max = inf and suppress the skip (conservative);
-    all-pad / all-invalid dominator tiles give min = inf and always skip —
-    which is where the win is: capacity-bucket overshoot fills whole
-    dominator tiles with padding, and in cross-set merges of sum-sorted
-    survivor prefixes entire (strong, weak) tile pairs clear the gap."""
+
+    Without ``yv_ref`` the max runs over EVERY victim, so a single +inf pad
+    victim makes it inf and no tile that holds one ever skips, whatever
+    its dominators. With ``yv_ref`` ((1, C) victim validity as 1.0 / 0.0)
+    invalid victims enter the max as -inf: a tile of invalid victims, or of
+    invalid dominators (min = inf) against finite valid victims, always
+    skips. That is where the win is: a vmapped merge pads every partition
+    to the largest one's prefix and batch block, on both sides of a pass.
+    A valid victim with an +inf coordinate keeps max = inf and its tile
+    runs (validity, never "+inf means pad")."""
     sx = x_ref[0, :]
     sy = y_ref[0, :]
     for k in range(1, d):  # static unroll over dimensions
         sx = sx + x_ref[k, :]
         sy = sy + y_ref[k, :]
     sx = jnp.where(v_ref[0, :] > 0.5, sx, jnp.inf)
+    if yv_ref is not None:
+        sy = jnp.where(yv_ref[0, :] > 0.5, sy, -jnp.inf)
     return jnp.min(sx) > jnp.max(sy)
+
+
+def tile_pairs(nx: int, ny: int, row_tile: int = ROW_TILE, col_tile: int = COL_TILE) -> int:
+    """Tile pairs one ``dominated_by_pallas`` launch over ``nx`` dominators
+    and ``ny`` victims runs through its grid."""
+    return (nx // min(row_tile, nx)) * (ny // min(col_tile, ny))
+
+
+def tiles_run(
+    xt: jax.Array,
+    x_valid: jax.Array,
+    yt: jax.Array,
+    y_valid: jax.Array | None = None,
+    row_tile: int = ROW_TILE,
+    col_tile: int = COL_TILE,
+) -> jax.Array:
+    """The tile pairs of one ``dominated_by_pallas(xt, x_valid, yt,
+    y_valid=y_valid)`` launch whose compute body runs, as an int32 scalar:
+    ``_tile_sum_skip``'s predicate per (row tile, col tile), from the same
+    f32 sums added in the same order. Plain jnp, so it runs wherever the
+    kernel would, and beside the XLA path too."""
+    d, nx = xt.shape
+    ny = yt.shape[1]
+    rt, ct = min(row_tile, nx), min(col_tile, ny)
+    sx, sy = xt[0], yt[0]
+    for k in range(1, d):
+        sx = sx + xt[k]
+        sy = sy + yt[k]
+    sx = jnp.where(x_valid, sx, jnp.inf)
+    if y_valid is not None:
+        sy = jnp.where(y_valid, sy, -jnp.inf)
+    lo = sx[: nx // rt * rt].reshape(nx // rt, rt).min(axis=1)
+    hi = sy[: ny // ct * ct].reshape(ny // ct, ct).max(axis=1)
+    return jnp.sum(~(lo[:, None] > hi[None, :]), dtype=jnp.int32)
 
 
 def _tile_rank_skip(d: int, x_ref, y_ref, v_ref):
@@ -220,17 +261,19 @@ def _kernel_tri(d: int, rt: int, ct: int, mp: bool, x_ref, v_ref, y_ref, out_ref
             _tile_body(d, mp, x_ref, y_ref, v_ref, out_ref)
 
 
-def _kernel(d: int, rt: int, ct: int, mp: bool, x_ref, v_ref, y_ref, out_ref):
+def _kernel(d: int, rt: int, ct: int, mp: bool, x_ref, v_ref, y_ref, *refs):
     # x_ref: (d, R) dominator coords; v_ref: (1, R) dominator validity as
     # float32 (Mosaic can't reshape 1-bit vectors across the minor dim);
-    # y_ref: (d, C) victim coords; out_ref: (1, C) accumulated dominated flags
+    # y_ref: (d, C) victim coords; refs: the (1, C) victim validity when
+    # the caller gave one, then out_ref, (1, C) accumulated dominated flags
+    *yv_ref, out_ref = refs
     i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(jnp.logical_not(_tile_sum_skip(d, x_ref, y_ref, v_ref)))
+    @pl.when(jnp.logical_not(_tile_sum_skip(d, x_ref, y_ref, v_ref, *yv_ref)))
     def _compute():
         _tile_body(d, mp, x_ref, y_ref, v_ref, out_ref)
 
@@ -434,30 +477,40 @@ def dominated_by_pallas(
     row_tile: int = ROW_TILE,
     col_tile: int = COL_TILE,
     mp: bool = False,
+    y_valid: jax.Array | None = None,
 ) -> jax.Array:
     """Rectangular variant: dominated[j] = any valid x_i dominates y_j.
 
     xt: (d, Nx) dominators (Nx % row_tile == 0); yt: (d, Ny) victims
     (Ny % col_tile == 0). The streaming flush's batch-vs-skyline prune maps
     here directly. ``mp=True`` enables the in-tile bf16 first pass.
+    ``y_valid`` (Ny,) bool lets the tile skip ignore invalid victims (see
+    ``_tile_sum_skip``); their verdicts are then unspecified, so the caller
+    masks them, as ``dominated_by_blocked``'s ``y_valid`` asks. ``None``
+    keeps every verdict exact.
     """
     d, nx = xt.shape
     _, ny = yt.shape
     rt, ct = min(row_tile, nx), min(col_tile, ny)
     grid = (ny // ct, nx // rt)
     v2 = x_valid[None, :].astype(jnp.float32)
+    in_specs = [
+        pl.BlockSpec((d, rt), lambda j, i: (0, i)),
+        pl.BlockSpec((1, rt), lambda j, i: (0, i)),
+        pl.BlockSpec((d, ct), lambda j, i: (0, j)),
+    ]
+    args = [xt, v2, yt]
+    if y_valid is not None:
+        in_specs.append(pl.BlockSpec((1, ct), lambda j, i: (0, j)))
+        args.append(y_valid[None, :].astype(jnp.float32))
     out = pl.pallas_call(
         functools.partial(_kernel, d, rt, ct, mp),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((d, rt), lambda j, i: (0, i)),
-            pl.BlockSpec((1, rt), lambda j, i: (0, i)),
-            pl.BlockSpec((d, ct), lambda j, i: (0, j)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, ct), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, ny), jnp.bool_),
         interpret=interpret,
-    )(xt, v2, yt)
+    )(*args)
     return out[0]
 
 

@@ -393,6 +393,10 @@ class PartitionSet:
         # stats path) + the high-water mark already fed to the telemetry
         # counters (flush_cascade_stats delta-feeds them)
         self._mp_resolved_dev = None
+        # incremental merge tile pairs whose kernel body ran, summed on the
+        # device while a profiler capture is on; read at the next global
+        # merge harvest (a sync the answer already makes)
+        self._tiles_run_dev = None
         self._bf16_resolved_reported = 0
         self.bf16_resolved = 0
         # a deferred (async-started) count-bound tighten from the last lazy
@@ -787,15 +791,25 @@ class PartitionSet:
                     _active_bucket(max(int(self._count_ub.max()), 1)),
                 )
                 if capturing():
+                    from skyline_tpu.ops.pallas_dominance import tile_pairs
+
                     # every partition runs against the largest one's
                     # prefix: the launch's pairs, and (from the host's
-                    # upper bounds, no sync) the pairs among live rows
+                    # upper bounds, no sync) the pairs among live rows;
+                    # the tile pairs its three kernel passes launch
                     CAPTURED.count(
                         "flush/merge_pairs", self.num_partitions * active * B
                     )
                     CAPTURED.count(
                         "flush/merge_live_pairs",
                         int((np.minimum(self._count_ub, active) * widths).sum()),
+                    )
+                    CAPTURED.count(
+                        "flush/merge_tiles",
+                        self.num_partitions * (
+                            tile_pairs(B, B) + tile_pairs(active, B)
+                            + tile_pairs(B, active)
+                        ),
                     )
                 if self._profiler is not None and profile_cost_enabled():
                     cost_thunk = self._merge_cost_thunk(
@@ -820,7 +834,7 @@ class PartitionSet:
                     # out_active is the SAME bucket out_cap grew from, so
                     # merge_step_active's max(cap, out_active) == out_cap
                     # structurally.
-                    self.sky, self.sky_valid, self._count_dev, res = (
+                    self.sky, self.sky_valid, self._count_dev, res, tiles = (
                         merge_step_active(
                             self.sky,
                             self.sky_valid,
@@ -831,6 +845,11 @@ class PartitionSet:
                             mp,
                         )
                     )
+                    if capturing():
+                        self._tiles_run_dev = (
+                            tiles if self._tiles_run_dev is None
+                            else self._tiles_run_dev + tiles
+                        )
                 if mp:
                     self._accum_resolved(res)
             self._cap = out_cap
@@ -2030,6 +2049,10 @@ class PartitionSet:
         P = self.num_partitions
         with self.tracer.phase("query/global_stats_sync"):
             svec = np.asarray(h.stats, dtype=np.int64)
+        if self._tiles_run_dev is not None:
+            # the flushes this merge read are done: the scalar is ready
+            CAPTURED.count("flush/merge_tiles_run", int(self._tiles_run_dev))
+            self._tiles_run_dev = None
         counts, surv, g = svec[:P].copy(), svec[P : 2 * P].copy(), int(svec[2 * P])
         if h.dirty is not None:
             self.merge_delta_merges += 1

@@ -155,6 +155,21 @@ def _mp_predrop(sky, sky_valid, batch, batch_valid):
     return batch, batch_valid, resolved
 
 
+def _merge_tiles_run(sky, sky_valid, batch, batch_valid, keep_batch):
+    """Tile pairs of the Pallas core's three passes whose compute body runs
+    (``ops.pallas_dominance.tiles_run``, each pass with the validity the
+    core hands its kernel), int32. Reckoned beside either core, so the
+    count reads the same wherever the merge ran."""
+    from skyline_tpu.ops.pallas_dominance import tiles_run
+
+    sky_t, batch_t = sky.T, batch.T
+    return (
+        tiles_run(batch_t, batch_valid, batch_t, batch_valid)
+        + tiles_run(sky_t, sky_valid, batch_t, batch_valid)
+        + tiles_run(batch_t, keep_batch, sky_t, sky_valid)
+    )
+
+
 def _merge_step_core(sky, sky_valid, batch, batch_valid, out_cap: int, mp: bool = False):
     """One windowed-BNL step: merge a new batch into a running skyline and
     compact survivors into a fresh ``out_cap`` buffer.
@@ -170,9 +185,10 @@ def _merge_step_core(sky, sky_valid, batch, batch_valid, out_cap: int, mp: bool 
 
     ``mp`` (static) enables the bf16 margin pre-drop (``_mp_predrop``) —
     bit-exact either way. Returns (values (out_cap, d), valid (out_cap,),
-    count, resolved); ``resolved`` is the int32 count of bf16-certified
-    drops (0 when ``mp=False``). ``out_cap`` must be >= current survivor
-    count + batch rows, so overflow cannot occur.
+    count, resolved, tiles); ``resolved`` is the int32 count of
+    bf16-certified drops (0 when ``mp=False``), ``tiles`` the Pallas core's
+    tile pairs that would run (``_merge_tiles_run``). ``out_cap`` must be
+    >= current survivor count + batch rows, so overflow cannot occur.
     """
     resolved = jnp.zeros((), dtype=jnp.int32)
     if mp:
@@ -185,14 +201,17 @@ def _merge_step_core(sky, sky_valid, batch, batch_valid, out_cap: int, mp: bool 
     x = jnp.concatenate([sky, batch], axis=0)
     keep = jnp.concatenate([keep_sky, keep_batch], axis=0)
     vals, valid, cnt = compact(x, keep, out_cap)
-    return vals, valid, cnt, resolved
+    tiles = _merge_tiles_run(sky, sky_valid, batch, batch_valid, keep_batch)
+    return vals, valid, cnt, resolved, tiles
 
 
 def _merge_step_pallas_core(sky, sky_valid, batch, batch_valid, out_cap: int, mp: bool = False):
     """TPU fast path of ``_merge_step_core``: the three dominance passes run
     in the Pallas VMEM-tiled kernel (same mask logic, same transitivity
     arguments; ``mp`` additionally threads the in-kernel bf16 first pass).
-    Requires sky/batch extents to be tile multiples — the
+    Each pass hands its kernel the victims' validity, so tiles of padding
+    skip; the verdicts of invalid victims are masked by ``batch_local`` /
+    ``sky_valid``. Requires sky/batch extents to be tile multiples — the
     _MIN_CAP floor plus pow2 capacities / pow2-or-tile-multiple active
     prefixes (``_active_bucket``) guarantee that."""
     from skyline_tpu.ops.pallas_dominance import dominated_by_pallas
@@ -206,18 +225,22 @@ def _merge_step_pallas_core(sky, sky_valid, batch, batch_valid, out_cap: int, mp
     sky_t = sky.T
     batch_t = batch.T
     batch_local = batch_valid & ~dominated_by_pallas(
-        batch_t, batch_valid, batch_t, interpret=interp, mp=mp
+        batch_t, batch_valid, batch_t, interpret=interp, mp=mp,
+        y_valid=batch_valid,
     )
     keep_batch = batch_local & ~dominated_by_pallas(
-        sky_t, sky_valid, batch_t, interpret=interp, mp=mp
+        sky_t, sky_valid, batch_t, interpret=interp, mp=mp,
+        y_valid=batch_valid,
     )
     keep_sky = sky_valid & ~dominated_by_pallas(
-        batch_t, keep_batch, sky_t, interpret=interp, mp=mp
+        batch_t, keep_batch, sky_t, interpret=interp, mp=mp,
+        y_valid=sky_valid,
     )
     x = jnp.concatenate([sky, batch], axis=0)
     keep = jnp.concatenate([keep_sky, keep_batch], axis=0)
     vals, valid, cnt = compact(x, keep, out_cap)
-    return vals, valid, cnt, resolved
+    tiles = _merge_tiles_run(sky, sky_valid, batch, batch_valid, keep_batch)
+    return vals, valid, cnt, resolved, tiles
 
 
 # Batched merge: P partitions' flushes in ONE device launch
@@ -265,7 +288,9 @@ def merge_step_active(
     tests/conftest.py, log-bounded in production by the doubling schedule).
 
     ``mp`` (static, a jit cache key) threads the bf16 margin pass; the
-    fourth return is the per-partition bf16-resolved count (P,) int32.
+    fourth return is the per-partition bf16-resolved count (P,) int32, the
+    fifth the tile pairs of the three passes whose body runs, summed over
+    partitions (int32 scalar, left on the device).
     """
     from skyline_tpu.ops.dispatch import on_tpu
 
@@ -273,7 +298,7 @@ def merge_step_active(
     core = _merge_step_pallas_core if on_tpu() else _merge_step_core
     sky_a = lax.slice(sky, (0, 0, 0), (P, active, d))
     val_a = lax.slice(sky_valid, (0, 0), (P, active))
-    vals, valid, cnt, res = jax.vmap(
+    vals, valid, cnt, res, tiles = jax.vmap(
         lambda s, sv, b, bv: core(s, sv, b, bv, out_active, mp)
     )(sky_a, val_a, batch, bvalid)
     out_cap = max(cap, out_active)
@@ -288,7 +313,7 @@ def merge_step_active(
         valid = jnp.concatenate(
             [valid, jnp.zeros((P, out_cap - out_active), dtype=bool)], axis=1
         )
-    return vals, valid, cnt.astype(jnp.int32), res
+    return vals, valid, cnt.astype(jnp.int32), res, jnp.sum(tiles, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("active", "union_cap"))
@@ -649,8 +674,14 @@ def tree_pair_merge(a, apids, acnt, b, bpids, bcnt, out_cap: int):
 
         interp = _pallas_interpret()
         at, bt = a.T, b.T
-        keep_b = bv & ~dominated_by_pallas(at, av, bt, interpret=interp)
-        keep_a = av & ~dominated_by_pallas(bt, keep_b, at, interpret=interp)
+        # victim validity lets the tile skip pass padding (invalid
+        # victims' verdicts are masked by av/bv below)
+        keep_b = bv & ~dominated_by_pallas(
+            at, av, bt, interpret=interp, y_valid=bv
+        )
+        keep_a = av & ~dominated_by_pallas(
+            bt, keep_b, at, interpret=interp, y_valid=av
+        )
     else:
         # chunk the dominator set so the dense tile stays ~256 MB; victim
         # validity tightens the sum-bound chunk skip (invalid victims may
@@ -732,7 +763,8 @@ def meshed_merge_step(mesh, axis: str, use_pallas: bool, out_cap: int, mp: bool 
     ride along (all-zero when ``mp=False``)."""
     core = _merge_step_pallas_core if use_pallas else _merge_step_core
     return _shard_map_vmapped(
-        mesh, axis, lambda s, sv, b, bv: core(s, sv, b, bv, out_cap, mp), 4, 4
+        mesh, axis, lambda s, sv, b, bv: core(s, sv, b, bv, out_cap, mp)[:4],
+        4, 4,
     )
 
 
