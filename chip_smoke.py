@@ -118,7 +118,8 @@ def http_get(port: int, path: str) -> str:
 
 
 def engine_config(window_capacity: int = 1_000_000):
-    """The north-star engine (bench.py's settings)."""
+    """The north-star engine: the ``anticorr8d-angle`` benchmark cell's
+    settings."""
     from skyline_tpu.stream import EngineConfig
 
     return EngineConfig(

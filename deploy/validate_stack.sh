@@ -22,7 +22,7 @@ if [ -z "$RUNTIME" ]; then
  "status": "blocked",
  "probed_at": "$(date -u +%FT%TZ)",
  "probe": {"docker": null, "podman": null, "nerdctl": null},
- "blocker": "no container runtime in this image and no package egress to install one; deploy/docker-compose.yml is untested here. Bare-metal equivalent of the same topology (kafkalite broker + worker + collector + producer as separate OS processes) runs via deploy/launch.py and is exercised by benchmarks/e2e_transport.py (artifacts/e2e_transport.json).",
+ "blocker": "no container runtime in this image and no package egress to install one; deploy/docker-compose.yml is untested here. Bare-metal equivalent of the same topology (kafkalite broker + worker + collector + producer as separate OS processes) runs via deploy/launch.py.",
  "how_to_run": "on a docker host: deploy/validate_stack.sh"
 }
 EOF

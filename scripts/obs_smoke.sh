@@ -8,10 +8,7 @@
 #     -> merge -> publish spans of the query just answered,
 #   * /stats carries latency_ms histogram summaries (p50/p99 tiles),
 #   * the --trace-out file written on close() validates the same way,
-# and finally exercise the bench regression gate both directions
-# (ok -> rc 0, forced regression -> rc 1) plus the perf-trajectory
-# sentinel (healthy history -> rc 0, injected rolling-baseline drift
-# -> rc 1).
+# then run the per-plane legs and the mesh, chaos and lint gates.
 #
 #   scripts/obs_smoke.sh
 #
@@ -631,7 +628,7 @@ EOF
 # replicated read fleet (RUNBOOK §2q): a WAL-tailing replica must expose
 # the full serve surface byte-identically (role-marked /healthz, labeled
 # per-tenant admission families on /metrics, SSE delta push on
-# /subscribe) and the perf sentinel must watch replica read lag
+# /subscribe)
 JAX_PLATFORMS=cpu python - <<'EOF'
 import hashlib
 import json
@@ -652,10 +649,6 @@ from skyline_tpu.serve import (
     delta_wal_record,
 )
 from skyline_tpu.serve.replica import SkylineReplica
-from skyline_tpu.telemetry.sentinel import DEFAULT_RULES
-
-assert any(r["label"] == "replica.read_lag_p99_ms" for r in DEFAULT_RULES), \
-    "sentinel does not watch replica read lag"
 
 wal_dir = tempfile.mkdtemp(prefix="skyline-replica-obs-")
 rng = np.random.default_rng(31)
@@ -733,18 +726,14 @@ try:
     assert 'skyline_serve_tenant_reads_admitted_total{tenant="t1"}' in prom
     print(f"[obs-smoke] replica surface ok: byte-identical read, "
           f"role-marked healthz, SSE delta push, {shed} tenant shed(s) "
-          f"labeled on /metrics, sentinel watches read lag")
+          f"labeled on /metrics")
 
     # ops plane (ISSUE 17, RUNBOOK §2s): replication telemetry as LABELED
     # families on the live replica exposition, the durable ops journal on
-    # /ops, the fleet overview on /cluster/overview, and the sentinel row
-    # watching replication lag
+    # /ops and the fleet overview on /cluster/overview
     from skyline_tpu.telemetry.clusterview import ClusterView
     from skyline_tpu.telemetry.opslog import OpsLog
 
-    assert any(r["label"] == "cluster.replication_lag_p99_ms"
-               for r in DEFAULT_RULES), \
-        "sentinel does not watch replication lag"
     assert 'skyline_replica_head_version{replica="obs-rep"}' in prom, \
         "labeled replica head gauge missing from exposition"
     assert 'skyline_replica_lag_ms{replica="obs-rep"}' in prom, \
@@ -788,8 +777,7 @@ EOF
 # store must be LIVE on the serve path — bodystore hit/torn/retry and
 # read-cache counters as Prometheus families on /metrics — and a
 # WAL-tailing replica must serve the primary's EXACT bytes (sha256)
-# out of the shared store, plus the sentinel must watch the load
-# harness's read p99 and shed fraction
+# out of the shared store
 JAX_PLATFORMS=cpu python - <<'EOF'
 import hashlib
 import json
@@ -808,11 +796,6 @@ from skyline_tpu.serve import (
 )
 from skyline_tpu.serve.bodystore import BodyStore
 from skyline_tpu.serve.replica import SkylineReplica
-from skyline_tpu.telemetry.sentinel import DEFAULT_RULES
-
-for label in ("serve_load.read_p99_ms", "serve_load.shed_fraction"):
-    assert any(r["label"] == label for r in DEFAULT_RULES), \
-        f"sentinel does not watch {label}"
 
 wal_dir = tempfile.mkdtemp(prefix="skyline-bodystore-obs-")
 rng = np.random.default_rng(47)
@@ -893,7 +876,7 @@ try:
     print(f"[obs-smoke] bodystore ok: {len(paths)} wire shapes "
           f"sha256-identical primary vs replica out of the shared store "
           f"({stats['hits']} replica ring hit(s), 0 torn), counter "
-          f"families live on /metrics, sentinel watches serve_load")
+          f"families live on /metrics")
 finally:
     rep.close()
     primary.close()
@@ -901,60 +884,6 @@ finally:
     writer.close()
     shutil.rmtree(wal_dir, ignore_errors=True)
 EOF
-
-# regression gate: newest two artifacts must currently pass at default
-# threshold, and an artificially regressed NEW must fail with rc 1
-python scripts/bench_compare.py
-REGRESSED="$(mktemp -d)"
-JAX_PLATFORMS=cpu python - "$REGRESSED" <<'EOF'
-import glob, json, os, sys
-dst = sys.argv[1]
-found = sorted(glob.glob("BENCH_r*.json"))
-assert found, "need a BENCH_r*.json artifact"
-# regress the newest artifact against ITSELF: halving NEW relative to a
-# different OLD round proves nothing (rounds legitimately differ 2x when a
-# config leg changes), so the trip-wire must be self-relative
-src = found[-1]
-for name in ("BENCH_r01.json", "BENCH_r02.json"):
-    with open(src) as f:
-        doc = json.load(f)
-    if name == "BENCH_r02.json":
-        doc["parsed"]["value"] *= 0.5  # force a 50% throughput regression
-    with open(os.path.join(dst, name), "w") as f:
-        json.dump(doc, f)
-EOF
-if python scripts/bench_compare.py --dir "$REGRESSED"; then
-  echo "[obs-smoke] FAIL: bench_compare missed a forced 50% regression" >&2
-  exit 1
-fi
-echo "[obs-smoke] bench_compare gate ok (pass + forced-regression trip)"
-
-# perf-trajectory sentinel (RUNBOOK 2o): the checked-in trajectory must be
-# healthy, and a slow drift — every pairwise step inside the bench_compare
-# threshold, but the newest round 40% below the rolling median — must trip
-# with rc 1 (exactly the regression shape the pairwise gate cannot see)
-python -m skyline_tpu.telemetry.sentinel --dir .
-DRIFTED="$(mktemp -d)"
-JAX_PLATFORMS=cpu python - "$DRIFTED" <<'EOF'
-import glob, json, os, sys
-dst = sys.argv[1]
-found = sorted(glob.glob("BENCH_r*.json"))
-assert found, "need a BENCH_r*.json artifact"
-with open(found[-1]) as f:
-    base = json.load(f)
-# self-relative trajectory: four steady rounds, then a drifted fifth whose
-# per-step deltas (~12% each) all pass pairwise but compound to -40%
-for r, scale in enumerate((1.00, 0.99, 1.01, 1.00, 0.60), start=1):
-    doc = json.loads(json.dumps(base))
-    doc["parsed"]["value"] *= scale
-    with open(os.path.join(dst, f"BENCH_r{r:02d}.json"), "w") as f:
-        json.dump(doc, f)
-EOF
-if python -m skyline_tpu.telemetry.sentinel --dir "$DRIFTED"; then
-  echo "[obs-smoke] FAIL: sentinel missed a 40% rolling-baseline drift" >&2
-  exit 1
-fi
-echo "[obs-smoke] sentinel ok (healthy trajectory + drift trip)"
 
 # sharded-engine gate: the two-level chip tournament lands byte-identical
 # to the flat worker and the chip-witness prefilter is live (RUNBOOK 2n)

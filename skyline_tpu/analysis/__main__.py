@@ -29,12 +29,11 @@ def repo_root() -> str:
 
 
 def default_roots(base: str) -> list[str]:
-    """The product tree: the package, bench harness, benchmarks, scripts,
-    deploy and the driver entry. Tests are excluded — they monkeypatch env
-    freely and fixture files deliberately contain violations."""
+    """The product tree: the package, scripts, deploy and the driver entry.
+    Tests are excluded — they monkeypatch env freely and fixture files
+    deliberately contain violations."""
     roots = []
-    for rel in ("skyline_tpu", "benchmarks", "scripts", "deploy",
-                "bench.py", "__graft_entry__.py"):
+    for rel in ("skyline_tpu", "scripts", "deploy", "__graft_entry__.py"):
         p = os.path.join(base, rel)
         if os.path.exists(p):
             roots.append(p)

@@ -175,8 +175,7 @@ def _cache_stability(jitted, make_args, label: str) -> list[Finding]:
 
 def run(dims=DEFAULT_DIMS, n: int = 256) -> tuple[list[Finding], dict]:
     """The full pass-2 matrix. Returns ``(findings, summary)``; the summary
-    (configs traced, backend, dims) is what bench.py stamps as the
-    ``analysis`` block's audit provenance."""
+    (configs traced, backend, dims) records what the audit covered."""
     import jax
     import jax.numpy as jnp
 

@@ -1,7 +1,7 @@
 """Pass 1 — knob lint: every env read goes through the declared registry.
 
-An ``ast`` walk over the product tree (package, bench harness, benchmarks,
-scripts, driver entry — tests excluded) enforcing:
+An ``ast`` walk over the product tree (package, scripts, deploy, driver
+entry — tests excluded) enforcing:
 
 - ``raw-env-read`` (error): ``os.environ.get`` / ``os.environ[...]`` /
   ``os.getenv`` / ``"X" in os.environ`` / ``os.environ.setdefault``
@@ -30,11 +30,8 @@ scripts, driver entry — tests excluded) enforcing:
   forks the decision.
 
 Suppression: a line containing ``# lint: allow-raw-env`` is exempt from
-``raw-env-read`` / ``dynamic-knob-name`` (used by the benchmark
-save/flip/restore idiom that snapshots knob values by name); a line
-containing ``# lint: allow-raw-gate`` is exempt from
-``raw-applicability`` (A/B harnesses comparing a gate's legacy default
-against the table-resolved value).
+``raw-env-read`` / ``dynamic-knob-name`` (used by the save/flip/restore
+idiom that snapshots knob values by name).
 """
 
 from __future__ import annotations
@@ -46,7 +43,6 @@ from skyline_tpu.analysis.findings import Finding
 from skyline_tpu.analysis.registry import ACCESSORS, _BY_NAME
 
 SUPPRESS = "# lint: allow-raw-env"
-SUPPRESS_GATE = "# lint: allow-raw-gate"
 
 # dispatch gate helpers whose calls must stay inside the cascade table
 # (ops/cascade.py) or their defining module (ops/dispatch.py). Anything
@@ -79,8 +75,8 @@ _TRUTHINESS_LITERALS = frozenset(
 
 # default directories/files skipped inside lint roots
 SKIP_DIRS = frozenset(
-    ("tests", "__pycache__", ".git", ".jax_cache", "artifacts",
-     "bench_out_cpu", "bench_out_tpu", "docs", "node_modules")
+    ("tests", "__pycache__", ".git", ".jax_cache", "artifacts", "docs",
+     "node_modules")
 )
 
 # the one module allowed to touch os.environ for knob reads
@@ -140,9 +136,9 @@ class _FileLint(ast.NodeVisitor):
         self.findings: list[Finding] = []
         self.reads: set[str] = set()  # knob names read via accessor
 
-    def _suppressed(self, node: ast.AST, marker: str = SUPPRESS) -> bool:
+    def _suppressed(self, node: ast.AST) -> bool:
         for ln in range(node.lineno, getattr(node, "end_lineno", node.lineno) + 1):
-            if ln - 1 < len(self.lines) and marker in self.lines[ln - 1]:
+            if ln - 1 < len(self.lines) and SUPPRESS in self.lines[ln - 1]:
                 return True
         return False
 
@@ -217,11 +213,7 @@ class _FileLint(ast.NodeVisitor):
                 "(use skyline_tpu.analysis.registry.env_*)",
             )
         gate = _gate_helper_name(node)
-        if (
-            gate is not None
-            and not self.is_table
-            and not self._suppressed(node, SUPPRESS_GATE)
-        ):
+        if gate is not None and not self.is_table:
             self._flag(
                 node, "raw-applicability",
                 f"{gate}() called outside the cascade table — resolve "

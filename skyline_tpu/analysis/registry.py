@@ -594,75 +594,6 @@ KNOBS: tuple[Knob, ...] = (
        "on a confirmed regime switch with no banked state, reset the "
        "mask/flush profiler signatures so the variant race re-runs under "
        "the new distribution", "engine", runbook="§2v"),
-    _k("SKYLINE_SENTINEL_WINDOW", "int", 4,
-       "perf-trajectory sentinel: rolling-baseline window (newest "
-       "artifact compared against the median of up to N prior comparable "
-       "rounds)", "telemetry", runbook="§2o"),
-    _k("SKYLINE_SENTINEL_THRESHOLD", "float", 0.3,
-       "perf-trajectory sentinel: default max fractional regression vs "
-       "the rolling baseline (per-metric rules can override)",
-       "telemetry", runbook="§2o"),
-    # -- bench harness (bench.py) ------------------------------------------
-    _k("BENCH_N", "int", 1_000_000, "window rows", "bench"),
-    _k("BENCH_D", "int", 8, "tuple dimensionality", "bench"),
-    _k("BENCH_WINDOWS", "int", 5, "measured windows", "bench"),
-    _k("BENCH_PARALLELISM", "int", 4, "engine parallelism", "bench"),
-    _k("BENCH_ALGO", "str", "mr-angle", "partitioner for the bench run",
-       "bench"),
-    _k("BENCH_BUFFER", "int", 8192, "per-partition buffer size", "bench"),
-    _k("BENCH_INITIAL_CAP", "int", 65536,
-       "pre-sized per-partition skyline capacity", "bench"),
-    _k("BENCH_FLUSH_POLICY", "str", "lazy", "flush policy for the bench run",
-       "bench"),
-    _k("BENCH_SERVE", "bool", True, "run the serving-plane bench leg",
-       "bench"),
-    _k("BENCH_SERVE_N", "int", 65536, "serve-leg window rows", "bench"),
-    _k("BENCH_SERVE_READERS", "int", 32, "serve-leg reader threads",
-       "bench"),
-    _k("BENCH_SERVE_READS", "int", 25, "serve-leg reads per reader",
-       "bench"),
-    _k("BENCH_REPLICA", "bool", True, "run the replica-plane bench leg",
-       "bench", runbook="§2q"),
-    _k("BENCH_REPLICA_PUBLISHES", "int", 40,
-       "replica-leg publish transitions tailed", "bench"),
-    _k("BENCH_REPLICA_ROWS", "int", 2048,
-       "replica-leg rows per published snapshot", "bench"),
-    _k("BENCH_LOAD", "bool", True,
-       "run the serve_load leg (benchmarks/loadgen.py multi-tenant A/B "
-       "harness)", "bench", runbook="§2u"),
-    _k("BENCH_LOAD_TENANTS", "int", 10_000,
-       "synthetic tenants in the load harness (zipf-skewed)", "bench",
-       runbook="§2u"),
-    _k("BENCH_LOAD_SECONDS", "float", 3.0,
-       "measured wall seconds per load-harness arm", "bench",
-       runbook="§2u"),
-    _k("BENCH_LOAD_WORKERS", "int", 8,
-       "concurrent client worker threads in the load harness", "bench",
-       runbook="§2u"),
-    _k("BENCH_LOAD_ZIPF", "float", 1.1,
-       "zipf exponent for tenant skew (higher = hotter head tenants)",
-       "bench", runbook="§2u"),
-    _k("BENCH_LOAD_BURST", "float", 0.05,
-       "burst-storm fraction: slice of request slots fired as "
-       "simultaneous storms against the head tenants", "bench",
-       runbook="§2u"),
-    _k("BENCH_LOAD_SSE", "int", 4,
-       "long-lived SSE subscriber connections held open during the load "
-       "run", "bench", runbook="§2u"),
-    _k("BENCH_CLUSTER", "bool", True,
-       "run the cluster-plane bench leg (host-prune probe + promotion "
-       "drill)", "bench", runbook="§2r"),
-    _k("BENCH_TUNER", "bool", True,
-       "run the dispatch-tuner A/B leg (benchmarks/tuner.py static-best "
-       "vs controller under drift, byte-identity asserted before timing)",
-       "bench", runbook="§2v"),
-    _k("BENCH_OPS", "bool", True,
-       "run the ops-plane bench leg (journal append cost + clusterview "
-       "scrape wall)", "bench", runbook="§2s"),
-    _k("BENCH_OPS_APPENDS", "int", 2000,
-       "ops-leg journal appends timed for the per-record cost", "bench"),
-    _k("BENCH_SERVE_POINTS", "bool", False,
-       "serve-leg full-payload reads instead of metadata-only", "bench"),
     # -- external (owned by JAX/XLA; declared for lint coverage) -----------
     _k("JAX_PLATFORMS", "str", None, "JAX backend selection (external)",
        "external", external=True),

@@ -355,7 +355,7 @@ def encode_record_batch(
     The record loop is the producer data plane's hot path (one iteration
     per message) — built with preassembled byte fragments and a zigzag
     varint inline fast path instead of per-record Writer objects
-    (~2.5x, benchmarks/e2e_transport.py drives it)."""
+    (~2.5x)."""
     n_records = len(records)
     parts: list[bytes] = []
     loop_records = records

@@ -525,8 +525,8 @@ class ClusterSupervisor:
             self.last_promotion = doc
             if self.telemetry is not None:
                 self.telemetry.inc("cluster.promotions")
-                # real histograms, not one-shot bench numbers: /slo's
-                # promote_p99 row and the sentinel read these
+                # real histograms, not one-shot numbers: /slo's
+                # promote_p99 row reads these
                 self.telemetry.histogram(
                     "cluster_time_to_promote_ms", unit="ms"
                 ).observe(wall_ms)

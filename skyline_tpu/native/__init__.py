@@ -2,8 +2,8 @@
 
 ``parse_tuples_native(text, dims)`` parses a newline-joined batch of
 data-plane lines into (ids, values, dropped) measured 11-13x faster than
-the Python line loop (1.37M vs 0.12M lines/s at 100k 8-D lines —
-artifacts/kernels_{cpu,tpu}.json, benchmarks/kernels.py). Returns None from
+the Python line loop (1.37M vs 0.12M lines/s at 100k 8-D lines, a host
+microbenchmark of July 2026). Returns None from
 ``get_lib()`` (and the wire module falls back to Python parsing) if no
 compiler is available or the build fails — the framework never
 hard-requires the native component.

@@ -17,8 +17,7 @@ Two tiers above the dense tile kernels in ``dominance.py``:
    (``ops.sfs.sfs_round_single`` — the same kernel the streaming engine's
    lazy flush policy uses for skewed partitions), tightening the dominator
    bound from lag-2 count reads that never stall the dispatch pipeline;
-   this single-set form is the library op and the microbench subject
-   (artifacts/kernels_*.json).
+   this single-set form is the library op.
 
 This replaces the reference's tuple-at-a-time BNL (FlinkSkyline.java:417-444),
 whose O(|buffer| x |skyline|) pointer-chasing loop is the system's documented
@@ -132,8 +131,7 @@ def skyline_mask_scan(
     ``nb`` sequential steps of one (chunk, N) tile each — an order of
     magnitude fewer dispatches than the (nb^2)-step nested scan in
     ``skyline_mask_blocked``, which is latency-bound on TPU for N ~ 10^5
-    (see artifacts/kernels_tpu.json for the measured scan-vs-blocked-vs-
-    Pallas table). Peak per-step memory is one (chunk, N) bool tile, so
+    (measured on the chip in July 2026). Peak per-step memory is one (chunk, N) bool tile, so
     ``chunk`` shrinks automatically as N grows.
 
     ``mp`` (static) prepends the bf16 margin pass: rows certified strictly
@@ -288,7 +286,7 @@ def skyline_large(
     true skyline size (O(N*(S+B)) total) without ever stalling the dispatch
     pipeline on a high-latency device link. The old per-block-synced XLA
     form measured 74 s on the 1M x 8D anti-correlated window
-    (artifacts/kernels_tpu.json, July 2026); this form runs the same
+    (on the chip, July 2026); this form runs the same
     kernels/shapes as the engine's SFS flush. Its own time on the chip is
     not measured yet.
 

@@ -1,9 +1,9 @@
 """Backend dispatch for the dominance hot ops.
 
 On TPU the Pallas kernel (VMEM-tiled, min/max cascade, triangular skip) is
-the fast path — see artifacts/kernels_tpu.json (benchmarks/kernels.py) for
-the measured Pallas-vs-scan table at several N. On CPU (tests, virtual
-meshes) Pallas would need interpret mode, so the scan kernel is used.
+the fast path (it beat the scan kernel at every N measured on the chip in
+July 2026; the benchmark's device trace shows it today). On CPU (tests,
+virtual meshes) Pallas would need interpret mode, so the scan kernel is used.
 Resolution happens once at first call.
 """
 
@@ -24,9 +24,8 @@ def rank_cascade() -> bool:
     for the self-skyline passes (ops/pallas_dominance.py rank kernels).
     Default OFF until the hardware A/B lands: the op-count argument (2 vs 3
     VPU ops/dim) favors ranks, but rank_transform's two sorts + searchsorted
-    per pass are unmeasured on TPU — run ``benchmarks/rank_cascade.py``
-    on the chip (writes artifacts/rank_cascade_ab.json) and flip the default only on a >=1.15x
-    measured win. Read lazily at trace time; already-compiled executables
+    per pass are unmeasured on TPU — A/B the two cascades on the chip and
+    flip the default only on a >=1.15x measured win. Read lazily at trace time; already-compiled executables
     are unaffected by later changes."""
     from skyline_tpu.analysis.registry import env_bool
 
@@ -41,7 +40,7 @@ def merge_cache_enabled() -> bool:
     union. Default ON — results are provably identical (merge law +
     transitivity, see PartitionSet.global_merge_stats); set ``0`` to force
     the from-scratch full merge on every trigger (the A/B baseline the
-    equivalence tests and benchmarks/merge_cache.py compare against). Read
+    equivalence tests compare against). Read
     lazily per query, so tests can flip it per-case."""
     from skyline_tpu.analysis.registry import env_bool
 
@@ -80,8 +79,7 @@ def merge_tree_enabled() -> bool:
     a halved, already-pruned candidate set instead of one O(U²) pass over
     the full union. Default ON for d > 2 (d <= 2 keeps the sort-sweep flat
     path, which is strictly cheaper); set ``0`` to force the flat union
-    merge — the A/B baseline tests/test_merge_tree.py and
-    benchmarks/merge_cache.py compare against. Results are byte-identical
+    merge — the A/B baseline tests/test_merge_tree.py compares against. Results are byte-identical
     either way (merge law + stable compaction order). Read lazily per
     query."""
     from skyline_tpu.analysis.registry import env_bool
@@ -114,8 +112,8 @@ def chip_prune_enabled() -> bool:
     interconnect. The soundness argument is the partition prune's
     (``merge_prune_enabled``) applied one level up, so the published bytes
     are identical either way. Default ON; set ``0`` to gather every
-    non-empty chip (the A/B baseline benchmarks/sharded_engine.py and
-    scripts/mesh_smoke.sh compare against). Read lazily per query."""
+    non-empty chip (the A/B baseline scripts/mesh_smoke.sh compares
+    against). Read lazily per query."""
     from skyline_tpu.analysis.registry import env_bool
 
     return env_bool("SKYLINE_CHIP_PRUNE", True)
@@ -130,8 +128,8 @@ def host_prune_enabled() -> bool:
     ZERO point rows to the coordinator — the chip prune
     (``chip_prune_enabled``) applied one level up, same soundness
     argument, so the published bytes are identical either way. Default
-    ON; set ``0`` to gather every non-empty host (the A/B baseline
-    benchmarks/cluster.py compares against). Read lazily per query."""
+    ON; set ``0`` to gather every non-empty host (the A/B baseline).
+    Read lazily per query."""
     from skyline_tpu.analysis.registry import env_bool
 
     return env_bool("SKYLINE_CLUSTER_HOST_PRUNE", True)
@@ -258,8 +256,8 @@ def freshness_enabled() -> bool:
     ``skyline_freshness_lag_ms{stage=...}`` histograms, and the
     ``staleness_ms`` field on ``/skyline``. Pure host bookkeeping — a few
     float compares per micro-batch, nothing inside jit — so default ON;
-    set ``0`` to drop even that (the A/B baseline in
-    ``benchmarks/freshness.py``). Read lazily at engine construction."""
+    set ``0`` to drop even that (the A/B baseline). Read lazily at engine
+    construction."""
     from skyline_tpu.analysis.registry import env_bool
 
     return env_bool("SKYLINE_FRESHNESS", True)
@@ -271,9 +269,8 @@ def kernel_profile_enabled() -> bool:
     dispatch is additionally timed under its (variant, d, N-bucket,
     backend, mp) signature and a ``kernel/<variant>`` span lands in the
     trace ring. Two ``perf_counter_ns`` reads + one lock per dispatch,
-    host-side only; default ON, set ``0`` for the unprofiled baseline
-    (``benchmarks/freshness.py`` A/B). Read lazily at engine
-    construction."""
+    host-side only; default ON, set ``0`` for the unprofiled baseline.
+    Read lazily at engine construction."""
     from skyline_tpu.analysis.registry import env_bool
 
     return env_bool("SKYLINE_KERNEL_PROFILE", True)
@@ -286,8 +283,8 @@ def explain_enabled() -> bool:
     served at ``GET /explain`` and inline via ``/skyline?explain=1``.
     Cost is a handful of counter snapshots and small dict writes per
     QUERY (zero per ingest batch, nothing inside jit), so default ON;
-    set ``0`` for the no-plan baseline (``benchmarks/explain.py`` A/B).
-    Read lazily at engine construction."""
+    set ``0`` for the no-plan baseline. Read lazily at engine
+    construction."""
     from skyline_tpu.analysis.registry import env_bool
 
     return env_bool("SKYLINE_EXPLAIN", True)
@@ -301,8 +298,8 @@ def audit_enabled() -> bool:
     repro bundles under ``SKYLINE_AUDIT_DIR``. Checks run host-side
     after the answer is already published — nothing enters jit and the
     hot path only pays a sampling-accumulator update — so default ON;
-    set ``0`` for the unaudited baseline (``benchmarks/audit.py`` A/B).
-    Read lazily at engine construction."""
+    set ``0`` for the unaudited baseline. Read lazily at engine
+    construction."""
     from skyline_tpu.analysis.registry import env_bool
 
     return env_bool("SKYLINE_AUDIT", True)
@@ -316,7 +313,7 @@ def fleet_enabled() -> bool:
     under the tournament merge, and ``GET /fleet``. Cost is a few list
     adds per flush/merge on the HOST side of an already host-orchestrated
     tournament (nothing inside jit; the identity law is unaffected —
-    ``benchmarks/fleet.py`` asserts byte-identity), so default ON; set
+    ``tests/test_fleet_plane.py`` asserts byte-identity), so default ON; set
     ``0`` for the unobserved baseline. No-op on flat (non-sharded)
     engines. Read lazily at engine construction."""
     from skyline_tpu.analysis.registry import env_bool
@@ -333,8 +330,8 @@ def workload_enabled() -> bool:
     substrate the ROADMAP's auto-tuner will read. Cost is one numpy pass
     over at most ``SKYLINE_WORKLOAD_SAMPLE_CAP`` rows per ingest batch
     (host-side, nothing inside jit, skyline bytes untouched), so default
-    ON; set ``0`` for the uncharacterized baseline
-    (``benchmarks/fleet.py`` A/B). Read lazily at engine construction."""
+    ON; set ``0`` for the uncharacterized baseline. Read lazily at engine
+    construction."""
     from skyline_tpu.analysis.registry import env_bool
 
     return env_bool("SKYLINE_WORKLOAD", True)
@@ -350,8 +347,7 @@ def tuner_enabled() -> bool:
     (``ops/cascade.py``), explicit env knobs always beat its overrides,
     and it stays passive until a workload epoch closes AND
     ``SKYLINE_TUNER_EPOCH_S`` elapses — so default ON; set ``0`` for the
-    static-dispatch baseline (``benchmarks/tuner.py`` A/B). Read lazily
-    at engine construction."""
+    static-dispatch baseline. Read lazily at engine construction."""
     from skyline_tpu.analysis.registry import env_bool
 
     return env_bool("SKYLINE_TUNER", True)

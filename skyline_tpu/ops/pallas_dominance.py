@@ -27,10 +27,9 @@ counted; equal values share a rank), and the strictness test collapses to
 ONE precomputed rank-sum compare per pair: ``a dominates b  <=>
 max_k(ra_k - rb_k) <= 0  AND  rsum_a < rsum_b`` (all-<= with equal sums
 forces equality in every dim since each term is <=). That is 2 VPU ops per
-dim + 2 instead of 3 per dim + 2 — see ``_dom_tile_rank``. The hardware
-A/B (benchmarks/rank_cascade.py -> artifacts/rank_cascade_ab.json) has not
-run on the chip; until it does the value cascade stays the default
-(ops/dispatch.py).
+dim + 2 instead of 3 per dim + 2 — see ``_dom_tile_rank``. The two
+cascades have not been A/B'd on the chip; until they are the value cascade
+stays the default (ops/dispatch.py).
 Rank sums stay exact in f32 (ranks < N <= 2^20, sums < d * N << 2^24).
 """
 
@@ -45,9 +44,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from skyline_tpu.ops.dominance import PAD_VALUE
 
-# (rows=dominators, cols=victims) per VMEM tile. Defaults picked by the
-# committed tile sweep (artifacts/kernels_tpu.json: 85 Gpairs/s at 512x2048
-# with the min/max cascade, vs 54 at the old 512x1024 bool-chain kernel).
+# (rows=dominators, cols=victims) per VMEM tile. Defaults picked by a tile
+# sweep on the chip in July 2026 (85 Gpairs/s at 512x2048 with the min/max
+# cascade, vs 54 at the old 512x1024 bool-chain kernel).
 # d<=16 keeps the unrolled cascade small.
 ROW_TILE = 512
 COL_TILE = 2048
@@ -58,8 +57,8 @@ def _dom_tile(d: int, x_ref, y_ref, v_ref, rows=slice(None), cols=slice(None)):
     ``x dominates y  <=>  max_k(x_k - y_k) <= 0  AND  min_k(x_k - y_k) < 0``
     — 3 f32 VPU ops per dimension (sub, max, min) instead of the naive
     4-op compare/bool chain, and the bool work collapses to one pair of
-    compares per tile. Measured ~1.6x the bool-chain kernel
-    (artifacts/kernels_tpu.json). ``rows`` / ``cols`` pick a sub-block of
+    compares per tile. Measured ~1.6x the bool-chain kernel (the same
+    July 2026 tile sweep). ``rows`` / ``cols`` pick a sub-block of
     the tile (the mixed-precision body rechecks one block at a time)."""
     diff = x_ref[0, rows][:, None] - y_ref[0, cols][None, :]
     mx = diff

@@ -1,8 +1,8 @@
 """Sorted-order SFS dominance cascade for d > 2 — the host sibling of the
 device SFS kernels (ISSUE 11).
 
-``flush/merge_kernel`` was ~98% of the BENCH_r06 profiled window, and the
-profile decomposes into exactly two cost modes (measured on the bench's
+``flush/merge_kernel`` was ~98% of a profiled 8-D anti-correlated window,
+and the profile decomposes into exactly two cost modes (measured on the
 8-D anti-correlated mr-angle stream):
 
 - the **duplicate-heavy partition**: the reference's 8-D anti-correlated
@@ -50,7 +50,7 @@ a tiny exact pairwise detour instead.
 Everything here is selection-only host NumPy: no arithmetic ever touches
 the returned rows, so byte-identity with the device kernels follows from
 mask equality (asserted across the kind × d × N grid by
-``benchmarks/sorted_sfs.py`` and ``tests/test_sorted_sfs.py``).
+``tests/test_sorted_sfs.py``).
 
 This path cannot run inside jit (it is host code; the jaxpr audit
 asserts it never leaks into a trace) — ``dispatch.skyline_mask_auto``

@@ -243,7 +243,7 @@ _PLAN: FaultPlan | None = None
 
 def fault_point(point: str, chip: int | None = None) -> None:
     """THE hot-path hook. With no plan installed this is one global load
-    and a None check — see benchmarks/resilience.py for the measured cost."""
+    and a None check (~80 ns on the host)."""
     plan = _PLAN
     if plan is not None:
         plan.hit(point, chip)

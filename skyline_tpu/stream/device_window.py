@@ -4,7 +4,7 @@ run on the accelerator instead of host numpy.
 The host ingest path (engine ``process_records`` + ``PartitionSet`` pending
 lists) computes partition ids, routes rows, sum-sorts and pads blocks in
 numpy, then uploads each padded block — ~1.2 s of host work per 1M-row
-window through the profiling breakdown (BENCH_r03). This module is the
+window in a 2026 profiling breakdown. This module is the
 keyBy-inside-the-dataflow equivalent (the reference keeps its shuffle inside
 the Flink job graph, FlinkSkyline.java:138): raw chunks upload once as they
 arrive (overlapping parse and transport), partition ids / per-chunk barrier

@@ -284,7 +284,8 @@ class SkylineEngine:
         # from the ingest path — per-dim quantile sketches, a correlation
         # estimate, and drift detection between consecutive epochs. All
         # host-side on a bounded deterministic sample; published skyline
-        # bytes are untouched on/off (benchmarks/fleet.py pins this). Hung
+        # bytes are untouched on/off (tests/test_workload_plane.py pins
+        # this). Hung
         # off the hub so both HTTP surfaces serve the ``workload`` block.
         self.workload = None
         if workload_enabled():

@@ -7,7 +7,7 @@ the serving north star needs:
 
 - ``histogram.Histogram`` — lock-cheap fixed-bucket latency distributions
   (ingest batch, query latency, global merge, serve reads) with p50/p90/p99
-  estimation; the single percentile implementation ``bench.py`` reports.
+  estimation; the single percentile implementation the plane reports.
 - ``spans.SpanRecorder`` — a bounded ring of per-query spans keyed by a
   ``trace_id`` minted at trigger ingestion, exportable as Chrome
   trace-event JSON (``GET /trace``, ``--trace-out``) for Perfetto.

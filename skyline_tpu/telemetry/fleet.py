@@ -27,7 +27,7 @@ everything downstream reads it:
 All of it is host-side integer/float bookkeeping outside every jitted
 computation — the sharded identity law (tournament root byte-identical to
 the flat merge) holds with the plane on or off
-(``benchmarks/fleet.py`` asserts this).
+(``tests/test_fleet_plane.py`` asserts this).
 """
 
 from __future__ import annotations

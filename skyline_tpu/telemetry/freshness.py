@@ -24,8 +24,7 @@ construction in the bundled bridge but keeps the whole chain honest for any
 source that supplies real event times via ``process_records(event_ms=...)``.
 
 Everything here is host-side floats and histogram observes — nothing enters
-a jitted computation, so skyline bytes are untouched (the A/B leg in
-``benchmarks/freshness.py`` asserts this).
+a jitted computation, so skyline bytes are untouched.
 
 Watermark semantics are monotone-max: advances never move the published
 watermark backwards, so ``staleness_ms`` is monotone non-increasing across

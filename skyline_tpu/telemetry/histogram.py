@@ -5,7 +5,7 @@ cannot answer "what does the p99 look like under load", which is the
 question every perf PR is graded on (ROADMAP north star). ``Histogram``
 is the shared distribution primitive for ingest batch time, query
 latency, global-merge time and serve read latency — and the single
-percentile implementation ``bench.py`` reports from.
+percentile implementation the telemetry plane reports from.
 
 Design points:
 

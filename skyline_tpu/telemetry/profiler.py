@@ -1,7 +1,7 @@
 """Per-kernel device profiling + a flight recorder of engine decisions.
 
-``flush/merge_kernel`` is ~98% of the profiled window (BENCH_r06) but the
-phase tracer reports it as one opaque total. ``KernelProfiler`` splits that
+``flush/merge_kernel`` was ~98% of a profiled 8-D anti-correlated window but
+the phase tracer reports it as one opaque total. ``KernelProfiler`` splits that
 wall time by *dispatch signature* — (variant, d, N-bucket, backend, mp) —
 the tuple that determines which compiled XLA executable actually ran. Each
 signature accumulates call count, wall-time total and EMA, a first-call

@@ -42,7 +42,7 @@ fast as allowed. A breach requires burn > 1 on BOTH windows, so a brief
 spike (fast window only) or old smoke (slow window only) doesn't page.
 
 Everything is pull-driven: no background thread, no cost until someone
-hits ``/slo`` or ``bench_compare`` evaluates the table. The clock is
+hits ``/slo``. The clock is
 injectable so tests drive the windows deterministically.
 """
 

@@ -38,8 +38,7 @@ frozen sketch range) emits a flight-recorder entry and bumps the
 Everything is host-side numpy on a bounded sample (``sample_cap`` rows
 per batch, deterministic stride — never the full batch); nothing enters a
 jitted computation, so published skyline bytes are untouched with the
-plane on or off (asserted in ``benchmarks/fleet.py`` and
-``tests/test_workload_plane.py``).
+plane on or off (asserted in ``tests/test_workload_plane.py``).
 """
 
 from __future__ import annotations

@@ -1,11 +1,8 @@
 """Observability integration tests: the StatsServer surface (/healthz,
-/stats, dashboard, /metrics, /trace), the serving plane's /metrics, the
-worker's --trace-out Chrome trace file, and the bench_compare gate."""
+/stats, dashboard, /metrics, /trace), the serving plane's /metrics and the
+worker's --trace-out Chrome trace file."""
 
 import json
-import os
-import subprocess
-import sys
 import urllib.error
 import urllib.request
 
@@ -16,8 +13,6 @@ from skyline_tpu.bridge import MemoryBus, SkylineWorker
 from skyline_tpu.bridge.wire import format_trigger, format_tuple_line
 from skyline_tpu.metrics.httpstats import StatsServer
 from skyline_tpu.telemetry import Telemetry
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _get(url, timeout=5):
@@ -229,78 +224,3 @@ def test_worker_metrics_on_stats_server(traced_worker, prom_parse):
     assert "skyline_ingest_batch_ms_bucket" in series
     assert series["skyline_query_latency_ms_count"][0][1] >= 1.0
     assert series["skyline_results_emitted"] == [({}, 1.0)]
-
-
-# ------------------------------------------------------------ bench gate
-
-
-def _run_compare(args, cwd):
-    return subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_compare.py")]
-        + args,
-        cwd=cwd,
-        capture_output=True,
-        text=True,
-        timeout=60,
-    )
-
-
-def _bench_doc(value, p50, backend="cpu-fallback"):
-    return {
-        "n": 1,
-        "rc": 0,
-        "parsed": {
-            "value": value,
-            "backend": backend,
-            "p50_window_latency_ms": p50,
-            "serve": {"read_p50_ms": 1.0, "read_p99_ms": 5.0},
-        },
-    }
-
-
-def test_bench_compare_ok_and_regression(tmp_path):
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps(_bench_doc(1000.0, 500.0))
-    )
-    (tmp_path / "BENCH_r02.json").write_text(
-        json.dumps(_bench_doc(1050.0, 480.0))
-    )
-    res = _run_compare(["--dir", str(tmp_path)], cwd=str(tmp_path))
-    assert res.returncode == 0, res.stdout + res.stderr
-    assert "ok" in res.stdout
-    # >25% throughput drop trips the gate
-    (tmp_path / "BENCH_r03.json").write_text(
-        json.dumps(_bench_doc(500.0, 480.0))
-    )
-    res = _run_compare(["--dir", str(tmp_path)], cwd=str(tmp_path))
-    assert res.returncode == 1
-    assert "REGRESSION" in res.stdout + res.stderr
-
-
-def test_bench_compare_latency_regression_and_threshold(tmp_path):
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps(_bench_doc(1000.0, 500.0)))
-    new.write_text(json.dumps(_bench_doc(1000.0, 600.0)))  # +20% p50
-    res = _run_compare([str(old), str(new)], cwd=str(tmp_path))
-    assert res.returncode == 0  # within default 25%
-    res = _run_compare(
-        [str(old), str(new), "--threshold", "0.10"], cwd=str(tmp_path)
-    )
-    assert res.returncode == 1
-
-
-def test_bench_compare_backend_mismatch_passes(tmp_path):
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps(_bench_doc(9000.0, 100.0, backend="tpu")))
-    new.write_text(json.dumps(_bench_doc(900.0, 1000.0)))
-    res = _run_compare([str(old), str(new)], cwd=str(tmp_path))
-    assert res.returncode == 0
-    assert "incomparable" in res.stdout
-
-
-def test_bench_compare_too_few_artifacts(tmp_path):
-    res = _run_compare(["--dir", str(tmp_path)], cwd=str(tmp_path))
-    assert res.returncode == 0
-    assert "nothing to compare" in res.stderr

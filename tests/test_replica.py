@@ -751,13 +751,6 @@ def test_replica_flags_parse_and_validate(tmp_path):
         ])
 
 
-def test_replica_sentinel_rule_registered():
-    from skyline_tpu.telemetry.sentinel import DEFAULT_RULES
-
-    labels = {r["label"] for r in DEFAULT_RULES}
-    assert "replica.read_lag_p99_ms" in labels
-
-
 # --------------------------------------------------------------------------
 # chaos acceptance: engine kill mid-burst
 # --------------------------------------------------------------------------

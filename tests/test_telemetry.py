@@ -30,7 +30,7 @@ from tests.conftest import parse_prometheus_text
 
 def test_histogram_small_sample_quantiles_exact():
     # below sample_cap the quantiles are true order statistics — identical
-    # to np.percentile(..., interpolation='linear'), which bench.py used
+    # to np.percentile(..., interpolation='linear')
     rng = np.random.default_rng(3)
     vals = rng.uniform(0.1, 5000.0, size=200)
     h = Histogram("t")
