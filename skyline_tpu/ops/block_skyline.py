@@ -348,9 +348,9 @@ def skyline_large(
         else:
             ub = rnd * block  # rows streamed so far bound the count
         active = min(cap, next_pow2(max(ub, 1), min_cap=1024))
-        sky, count, _ = sfs_round_single(
+        sky, count = sfs_round_single(
             sky, count, jnp.asarray(blk), jnp.asarray(bvalid), active, mp
-        )
+        )[:2]
         counts.append(count)
 
     k = int(count)  # the final sync

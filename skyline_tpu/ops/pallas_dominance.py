@@ -192,10 +192,24 @@ def _tile_sum_skip(d: int, x_ref, y_ref, v_ref, yv_ref=None):
     return jnp.min(sx) > jnp.max(sy)
 
 
-def tile_pairs(nx: int, ny: int, row_tile: int = ROW_TILE, col_tile: int = COL_TILE) -> int:
+def tile_pairs(
+    nx: int,
+    ny: int,
+    row_tile: int = ROW_TILE,
+    col_tile: int = COL_TILE,
+    triangular: bool = False,
+) -> int:
     """Tile pairs one ``dominated_by_pallas`` launch over ``nx`` dominators
-    and ``ny`` victims runs through its grid."""
-    return (nx // min(row_tile, nx)) * (ny // min(col_tile, ny))
+    and ``ny`` victims runs through its grid. ``triangular``: those of a
+    triangular ``dominated_by_any_pallas`` launch over ``nx == ny`` rows,
+    which never enters a row tile that starts past its column tile's end
+    (``_kernel_tri``)."""
+    rt, ct = min(row_tile, nx), min(col_tile, ny)
+    if triangular:
+        return sum(
+            min(nx // rt, (j * ct + ct - 1) // rt + 1) for j in range(ny // ct)
+        )
+    return (nx // rt) * (ny // ct)
 
 
 def tiles_run(
@@ -205,12 +219,16 @@ def tiles_run(
     y_valid: jax.Array | None = None,
     row_tile: int = ROW_TILE,
     col_tile: int = COL_TILE,
+    triangular: bool = False,
 ) -> jax.Array:
     """The tile pairs of one ``dominated_by_pallas(xt, x_valid, yt,
     y_valid=y_valid)`` launch whose compute body runs, as an int32 scalar:
     ``_tile_sum_skip``'s predicate per (row tile, col tile), from the same
-    f32 sums added in the same order. Plain jnp, so it runs wherever the
-    kernel would, and beside the XLA path too."""
+    f32 sums added in the same order. ``triangular`` (``yt`` is ``xt``,
+    ``y_valid`` None): those of ``dominated_by_any_pallas(xt, x_valid,
+    triangular=True)``, whose grid also skips the pairs ``_kernel_tri``
+    never enters. Plain jnp, so it runs wherever the kernel would, and
+    beside the XLA path too."""
     d, nx = xt.shape
     ny = yt.shape[1]
     rt, ct = min(row_tile, nx), min(col_tile, ny)
@@ -223,7 +241,12 @@ def tiles_run(
         sy = jnp.where(y_valid, sy, -jnp.inf)
     lo = sx[: nx // rt * rt].reshape(nx // rt, rt).min(axis=1)
     hi = sy[: ny // ct * ct].reshape(ny // ct, ct).max(axis=1)
-    return jnp.sum(~(lo[:, None] > hi[None, :]), dtype=jnp.int32)
+    run = ~(lo[:, None] > hi[None, :])
+    if triangular:
+        i = jnp.arange(nx // rt)[:, None]
+        j = jnp.arange(ny // ct)[None, :]
+        run = run & (i * rt <= j * ct + ct - 1)
+    return jnp.sum(run, dtype=jnp.int32)
 
 
 def _tile_rank_skip(d: int, x_ref, y_ref, v_ref):

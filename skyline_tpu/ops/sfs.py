@@ -41,7 +41,6 @@ from skyline_tpu.ops.dispatch import on_tpu
 from skyline_tpu.ops.dominance import (
     compact,
     dominated_by,
-    skyline_mask,
     strictly_dominated_bf16,
 )
 
@@ -66,6 +65,15 @@ def pallas_interpret() -> bool:
     return env_bool("SKYLINE_PALLAS_INTERPRET", False)
 
 
+def adjacent_duplicates(x, valid):
+    """(N,) bool: row ``i`` and row ``i - 1`` are both valid and equal in
+    every coordinate — the followers of each run of adjacent equal valid
+    rows. The first row of every run stays unmarked, an invalid row breaks
+    a run, and a row holding a NaN never equals anything."""
+    same = jnp.all(x[1:] == x[:-1], axis=1) & valid[1:] & valid[:-1]
+    return jnp.concatenate([jnp.zeros((1,), dtype=bool), same])
+
+
 def sfs_round_core(sky, count, block, bvalid, active, use_pallas, interp, mp=False):
     """One SFS append round for one partition.
 
@@ -83,12 +91,31 @@ def sfs_round_core(sky, count, block, bvalid, active, use_pallas, interp, mp=Fal
     implies the exact sky-vs-block pass drops the row too, and any block
     row it would itself have pruned is strictly dominated by the same sky
     row (transitivity), so the survivor set and the stable compact order
-    are unchanged. Returns ``(sky, count, resolved)``; ``resolved`` is the
-    int32 count of bf16-certified drops (0 when ``mp=False``).
+    are unchanged.
+
+    Both passes take the followers of each run of adjacent equal rows
+    (``adjacent_duplicates``) out of the dominators, never out of the
+    victims. Equal rows dominate the same victims and the run's first row
+    stays a dominator, so every verdict is unchanged; what changes is the
+    kernels' sum-bound tile skip, which a tile of copies (tying on every
+    sum) otherwise never passes. The rectangular pass also hands the skip
+    the victims' validity. Every copy is still a victim, so every copy
+    survives as before.
+
+    Returns ``(sky, count, resolved, tiles)``: ``resolved`` is the int32
+    count of bf16-certified drops (0 when ``mp=False``), ``tiles`` the int32
+    count of the two Pallas passes' tile pairs whose body runs
+    (``tiles_run``), reckoned beside either path.
 
     Caller guarantees count + B <= cap (the compacted block writes B slots;
     rows past the survivor count are +inf padding landing on virgin rows).
     """
+    from skyline_tpu.ops.pallas_dominance import (
+        dominated_by_any_pallas,
+        dominated_by_pallas,
+        tiles_run,
+    )
+
     cap, d = sky.shape
     sky_act = lax.slice(sky, (0, 0), (active, d))
     sky_ok = jnp.arange(active) < count
@@ -107,25 +134,25 @@ def sfs_round_core(sky, count, block, bvalid, active, use_pallas, interp, mp=Fal
         # replaced row only moves UP in sum, and its own column's verdict
         # is masked out by bvalid)
         block = jnp.where(bvalid[:, None], block, jnp.inf)
+    sky_dom = sky_ok & ~adjacent_duplicates(sky_act, sky_ok)
+    block_dom = bvalid & ~adjacent_duplicates(block, bvalid)
+    sky_t, block_t = sky_act.T, block.T
     if use_pallas:
-        from skyline_tpu.ops.pallas_dominance import (
-            dominated_by_any_pallas,
-            dominated_by_pallas,
-        )
-
-        block_t = block.T
         keep = bvalid & ~dominated_by_any_pallas(
-            block_t, bvalid, triangular=True, interpret=interp, mp=mp
+            block_t, block_dom, triangular=True, interpret=interp, mp=mp
         )
         keep = keep & ~dominated_by_pallas(
-            sky_act.T, sky_ok, block_t, interpret=interp, mp=mp
+            sky_t, sky_dom, block_t, interpret=interp, mp=mp, y_valid=bvalid
         )
     else:
-        keep = skyline_mask(block, bvalid)
-        keep = keep & ~dominated_by(block, sky_act, x_valid=sky_ok)
+        keep = bvalid & ~dominated_by(block, block, x_valid=block_dom)
+        keep = keep & ~dominated_by(block, sky_act, x_valid=sky_dom)
+    tiles = tiles_run(sky_t, sky_dom, block_t, bvalid) + tiles_run(
+        block_t, block_dom, block_t, triangular=True
+    )
     vals, _, m = compact(block, keep, block.shape[0])
     sky = lax.dynamic_update_slice(sky, vals, (count, 0))
-    return sky, count + m, resolved
+    return sky, count + m, resolved, tiles
 
 
 @functools.partial(
@@ -134,9 +161,9 @@ def sfs_round_core(sky, count, block, bvalid, active, use_pallas, interp, mp=Fal
 def sfs_round(sky, counts, blocks, bvalids, active: int, mp: bool = False):
     """Vmapped SFS round over all partitions: sky (P, cap, d), counts (P,)
     int32, blocks (P, B, d), bvalids (P, B) -> (sky', counts', resolved
-    (P,)). One device launch for the whole set — right when partitions
-    carry comparable row counts (every vmap lane computes the full
-    (B x active) passes whether its block is real or padding; see
+    (P,), tiles (P,)). One device launch for the whole set — right when
+    partitions carry comparable row counts (every vmap lane computes the
+    full (B x active) passes whether its block is real or padding; see
     ``sfs_round_single`` for the skewed case). ``mp`` (static) threads the
     mixed-precision pass — a jit cache key, so flipping the env gate
     really switches executables."""
@@ -159,7 +186,7 @@ def sfs_round_single(sky_p, count, block, bvalid, active: int, mp: bool = False)
     anti-correlated routes ~96% of rows to 2 of 8 partitions) the vmapped
     round pays P lanes of (B x active) work for one real lane; processing
     the heavy partitions individually costs exactly their own rows.
-    Returns (sky', count', resolved)."""
+    Returns (sky', count', resolved, tiles)."""
     return sfs_round_core(
         sky_p, count, block, bvalid, active, on_tpu(), pallas_interpret(), mp
     )

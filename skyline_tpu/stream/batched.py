@@ -395,8 +395,10 @@ class PartitionSet:
         self._mp_resolved_dev = None
         # incremental merge tile pairs whose kernel body ran, summed on the
         # device while a profiler capture is on; read at the next global
-        # merge harvest (a sync the answer already makes)
+        # merge harvest (a sync the answer already makes); the SFS rounds'
+        # twin beside it
         self._tiles_run_dev = None
+        self._sfs_tiles_run_dev = None
         self._bf16_resolved_reported = 0
         self.bf16_resolved = 0
         # a deferred (async-started) count-bound tighten from the last lazy
@@ -928,9 +930,10 @@ class PartitionSet:
                         self.sky, counts, batch_dev, bvalid_dev
                     )
                 else:
-                    self.sky, counts, res = sfs_round(
+                    self.sky, counts, res, tiles = sfs_round(
                         self.sky, counts, batch_dev, bvalid_dev, active, mp
                     )
+                    self._count_sfs_tiles(tiles, active, B)
                 if mp:
                     self._accum_resolved(res)
             prev.append((counts, widths))
@@ -1057,9 +1060,10 @@ class PartitionSet:
                     with self.tracer.phase("flush/merge_kernel"), (
                         self._kernel("sfs_sequential", active, mp)
                     ):
-                        sky_p, cnt_p, res = sfs_round_single(
+                        sky_p, cnt_p, res, tiles = sfs_round_single(
                             sky_p, cnt_p, block_dev, bvalid_dev, active, mp
                         )
+                        self._count_sfs_tiles(tiles, active, B)
                         if mp:
                             self._accum_resolved(res)
                     prev.append((cnt_p, w))
@@ -1146,10 +1150,11 @@ class PartitionSet:
                                 off, w, B=B, active=active,
                             )
                         else:
-                            sky_p, cnt_p, res = dw.sfs_round_at(
+                            sky_p, cnt_p, res, tiles = dw.sfs_round_at(
                                 sky_p, cnt_p, ws, off, w,
                                 B=B, active=active, mp=mp,
                             )
+                            self._count_sfs_tiles(tiles, active, B)
                             if mp:
                                 self._accum_resolved(res)
                     prev.append((cnt_p, w))
@@ -1172,6 +1177,27 @@ class PartitionSet:
         self._cap = new_cap
 
     # -- flush dominance cascade (grid prefilter + mixed precision) ---------
+
+    def _count_sfs_tiles(self, tiles, active: int, B: int) -> None:
+        """While a profiler capture is on, count the tile pairs one SFS
+        round's two Pallas passes launch (``flush/sfs_tiles``, from the
+        shapes: every lane a (B x active) rectangular and a triangular B
+        pass) and fold those whose body runs (``tiles``, one per lane) into
+        a device scalar, read at the next global merge harvest."""
+        if not capturing():
+            return
+        from skyline_tpu.ops.pallas_dominance import tile_pairs
+
+        CAPTURED.count(
+            "flush/sfs_tiles",
+            tiles.size
+            * (tile_pairs(active, B) + tile_pairs(B, B, triangular=True)),
+        )
+        t = jnp.sum(tiles, dtype=jnp.int32)
+        self._sfs_tiles_run_dev = (
+            t if self._sfs_tiles_run_dev is None
+            else self._sfs_tiles_run_dev + t
+        )
 
     def _accum_resolved(self, res) -> None:
         """Fold one round's bf16-resolved counts into the running device
@@ -1854,10 +1880,11 @@ class PartitionSet:
                         offs_d, w_d, B=B, active=active,
                     )
                 else:
-                    self.sky, counts, res = dw.sfs_round_at_vmapped(
+                    self.sky, counts, res, tiles = dw.sfs_round_at_vmapped(
                         self.sky, counts, ws, offs_d, w_d,
                         B=B, active=active, mp=mp,
                     )
+                    self._count_sfs_tiles(tiles, active, B)
                     if mp:
                         self._accum_resolved(res)
             prev.append((counts, w))
@@ -2049,10 +2076,13 @@ class PartitionSet:
         P = self.num_partitions
         with self.tracer.phase("query/global_stats_sync"):
             svec = np.asarray(h.stats, dtype=np.int64)
+        # the flushes this merge read are done: the scalars are ready
         if self._tiles_run_dev is not None:
-            # the flushes this merge read are done: the scalar is ready
             CAPTURED.count("flush/merge_tiles_run", int(self._tiles_run_dev))
             self._tiles_run_dev = None
+        if self._sfs_tiles_run_dev is not None:
+            CAPTURED.count("flush/sfs_tiles_run", int(self._sfs_tiles_run_dev))
+            self._sfs_tiles_run_dev = None
         counts, surv, g = svec[:P].copy(), svec[P : 2 * P].copy(), int(svec[2 * P])
         if h.dirty is not None:
             self.merge_delta_merges += 1

@@ -327,7 +327,8 @@ def sfs_round_at(sky_p, count, win, off, width, *, B: int, active: int, mp: bool
     in the sorted order — masked to +inf so they are inert as dominators
     and never appended. Drop-in device-window twin of
     ``ops.sfs.sfs_round_single`` — ``mp`` (static) threads the
-    mixed-precision pass and the bf16-resolved count rides third."""
+    mixed-precision pass; the bf16-resolved count rides third and the
+    tile pairs run fourth."""
     d = win.shape[1]
     block = lax.dynamic_slice(win, (off, jnp.zeros((), jnp.int32)), (B, d))
     bvalid = jnp.arange(B) < width

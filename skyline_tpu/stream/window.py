@@ -774,7 +774,8 @@ def meshed_sfs_round(mesh, axis: str, use_pallas: bool, active: int, mp: bool = 
     ``_shard_map_vmapped``) — the lazy policy's meshed flush. Cached per
     (mesh, axis, kernel, active bucket, mixed-precision flag); donates the
     sky buffer like the single-device jit. Returns 3 outputs — per-partition
-    bf16-resolved counts third (all-zero when ``mp=False``)."""
+    bf16-resolved counts third (all-zero when ``mp=False``); the core's tile
+    count stays behind."""
     from skyline_tpu.ops.sfs import pallas_interpret, sfs_round_core
 
     interp = pallas_interpret()
@@ -783,7 +784,7 @@ def meshed_sfs_round(mesh, axis: str, use_pallas: bool, active: int, mp: bool = 
         axis,
         lambda s, c, b, bv: sfs_round_core(
             s, c, b, bv, active, use_pallas, interp, mp
-        ),
+        )[:3],
         4,
         3,
         donate=(0,),

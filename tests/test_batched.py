@@ -208,8 +208,8 @@ def test_sfs_round_single_matches_vmapped(rng):
     for rnd in range(2):
         batch = np.stack([x[rnd * B:(rnd + 1) * B] for x in parts])
         bvalid = np.ones((P, B), dtype=bool)
-        sky_v, cnt_v, _ = sfs_round(
-            sky_v, cnt_v, jnp.asarray(batch), jnp.asarray(bvalid), cap)
+        sky_v, cnt_v = sfs_round(
+            sky_v, cnt_v, jnp.asarray(batch), jnp.asarray(bvalid), cap)[:2]
         singles = [
             sfs_round_single(s, c, jnp.asarray(batch[p]),
                              jnp.asarray(bvalid[p]), cap)[:2]
