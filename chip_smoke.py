@@ -117,9 +117,9 @@ def http_get(port: int, path: str) -> str:
         return r.read().decode()
 
 
-def engine_config(window_capacity: int = 1_000_000):
+def engine_config(window_capacity: int = 1_000_000, mesh_chips: int = 0):
     """The north-star engine: the ``anticorr8d-angle`` benchmark cell's
-    settings."""
+    settings (``mesh_chips=4``: the ``anticorr8d-angle-4chip`` cell's)."""
     from skyline_tpu.stream import EngineConfig
 
     return EngineConfig(
@@ -131,6 +131,7 @@ def engine_config(window_capacity: int = 1_000_000):
         initial_capacity=65536,
         flush_policy="lazy",
         window_capacity=window_capacity,
+        mesh_chips=mesh_chips,
     )
 
 
@@ -277,7 +278,7 @@ def run_four_chips(seed: int, rows: int) -> None:
     answers = {}
     for name, kw in (
         ("flat", {}),
-        ("sharded", {"mesh_chips": 4}),
+        ("sharded", {"config": engine_config(mesh_chips=4)}),
         ("meshed", {"mesh": make_mesh(4)}),
     ):
         bus, worker = make_worker(**kw)

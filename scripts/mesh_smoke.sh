@@ -49,8 +49,7 @@ def run(mesh_chips):
         argv += ["--mesh-chips", str(mesh_chips)]
     cfg = parse_job_args(argv)
     bus = MemoryBus()
-    w = SkylineWorker(bus, cfg.engine_config(), stats_port=cfg.stats_port,
-                      mesh_chips=cfg.mesh_chips)
+    w = SkylineWorker(bus, cfg.engine_config(), stats_port=cfg.stats_port)
     try:
         rng = np.random.default_rng(11)
         x = anti_correlated(rng, 6000, 4, 0, 10000)

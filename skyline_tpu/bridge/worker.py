@@ -35,7 +35,6 @@ class SkylineWorker:
         query_topic: str = QUERY_TOPIC,
         output_topic: str = OUTPUT_TOPIC,
         mesh=None,
-        mesh_chips: int = 0,
         cluster_hosts: int = 0,
         stats_port: int | None = None,
         window_size: int = 0,
@@ -53,12 +52,13 @@ class SkylineWorker:
         replicas: int = 0,
     ):
         """``mesh``: optional ``jax.sharding.Mesh`` — partition state shards
-        across its devices (multi-chip streaming). ``mesh_chips``: > 0
-        runs the sharded streaming engine (``distributed/``) — the
+        across its devices (multi-chip streaming). ``config.mesh_chips``
+        > 0 runs the sharded streaming engine (``distributed/``) — the
         partition set splits into that many per-chip groups and queries
         are answered by the two-level tournament merge; byte-identical
         results, mutually exclusive with ``mesh`` and sliding-window
-        mode (RUNBOOK §2n). ``stats_port``: serve
+        mode (RUNBOOK §2n); with ``cluster_hosts`` it is the chip count
+        per host. ``stats_port``: serve
         live /stats + /healthz JSON on this port (0 picks a free one; None
         disables) — the Flink-Web-UI role for this stack. ``window_size`` +
         ``slide`` (both > 0) switch the worker to the sliding-window engine
@@ -102,6 +102,7 @@ class SkylineWorker:
         from skyline_tpu.metrics.tracing import Tracer
         from skyline_tpu.telemetry import Telemetry
 
+        mesh_chips = int(config.mesh_chips)
         if mesh_chips and mesh is not None:
             raise ValueError("mesh and mesh_chips are mutually exclusive")
         if mesh_chips and window_size:
@@ -114,7 +115,7 @@ class SkylineWorker:
             raise ValueError(
                 "sliding-window mode does not support cluster_hosts"
             )
-        self.mesh_chips = int(mesh_chips)
+        self.mesh_chips = mesh_chips
         self.cluster_hosts = int(cluster_hosts)
         self.bus = bus
         self.max_drain_polls = max_drain_polls
@@ -212,7 +213,8 @@ class SkylineWorker:
             elif restored_engine is not None:
                 # the checkpoint carries its full EngineConfig; trust it over the
                 # passed config so a restarted incarnation can't silently change
-                # result semantics mid-stream
+                # result semantics mid-stream (the chip count is this
+                # worker's own: restore_latest re-applied it)
                 self.engine = restored_engine
             elif cluster_hosts:
                 # multi-host cluster ingest (RUNBOOK §2r): mesh_chips becomes
@@ -1257,7 +1259,6 @@ def main(argv=None):
         query_topic=cfg.query_topic,
         output_topic=cfg.output_topic,
         mesh=cfg.build_mesh(),
-        mesh_chips=cfg.mesh_chips,
         cluster_hosts=cfg.cluster_hosts,
         stats_port=cfg.stats_port if cfg.stats_port > 0 else None,
         window_size=cfg.window_size,

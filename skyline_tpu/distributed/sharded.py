@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from skyline_tpu.metrics.tracing import NULL_TRACER
+from skyline_tpu.metrics.tracing import CAPTURED, NULL_TRACER
 from skyline_tpu.ops import cascade
 from skyline_tpu.ops.dispatch import (
     chip_failover_enabled,
@@ -366,12 +366,17 @@ class ShardedPartitionSet:
         return False
 
     def flush_all(self, tighten: bool = True) -> None:
+        """Flush every chip in turn. While a profiler capture is on,
+        counts the rows flushed (``flush/chip_rows``) and the fullest
+        chip's share of them (``flush/chip_rows_max``)."""
+        chip_rows = []
         for c, chip in enumerate(self._chips):
             # the chip lock serializes the flush against any
             # deadline-abandoned merge attempt still in flight on this
             # group (_bounded_level1); uncontended on a healthy fleet
             with self._chip_locks[c]:
                 rows = chip.pending_rows_total
+                chip_rows.append(rows)
                 t0 = time.perf_counter_ns()
                 with self._dev(c):
                     chip.flush_all(tighten)
@@ -386,6 +391,8 @@ class ShardedPartitionSet:
                 # the liveness feed behind ChipHealth's staleness tick
                 self._health.note_heartbeat(c)
         self._pending_rows[:] = 0
+        CAPTURED.count("flush/chip_rows", sum(chip_rows))
+        CAPTURED.count("flush/chip_rows_max", max(chip_rows))
 
     def flush_cascade_stats(self) -> dict:
         docs = [c.flush_cascade_stats() for c in self._chips]
@@ -476,21 +483,32 @@ class ShardedPartitionSet:
         bounded = deadline_ms > 0 and C > 1
         failed: list[dict] = []
         for c, chip in enumerate(self._chips):
-            t0 = time.perf_counter_ns()
-            if bounded:
-                br = self._bounded_level1(
-                    c, chip, want_prune, deadline_ms, failed
-                )
-                # the winning attempt's own wall (fault latency + merge,
-                # no backoff sleeps / hedge waits / failed attempts) —
-                # anything else would pollute the peer-median straggler
-                # signal with scheduler overhead
-                r, t0, t1 = br if br is not None else (None, t0, t0)
-            else:
-                fault_point("sharded.chip_merge", chip=c)
-                with self._chip_locks[c]:
-                    r = self._level1_chip(c, chip, want_prune)
-                t1 = time.perf_counter_ns()
+            # level-1 phase; its span in /trace shows which chip's local
+            # tournament the merge wall went to
+            with self.tracer.span_phase(
+                "cross_chip/level1", self._spans, "chip_merge",
+                trace_id=trace_id, tid=c + 1,
+            ) as ph:
+                t0 = time.perf_counter_ns()
+                if bounded:
+                    br = self._bounded_level1(
+                        c, chip, want_prune, deadline_ms, failed
+                    )
+                    # the winning attempt's own wall (fault latency +
+                    # merge, no backoff sleeps / hedge waits / failed
+                    # attempts) — anything else would pollute the
+                    # peer-median straggler signal with scheduler overhead
+                    r, t0, t1 = br if br is not None else (None, t0, t0)
+                else:
+                    fault_point("sharded.chip_merge", chip=c)
+                    with self._chip_locks[c]:
+                        r = self._level1_chip(c, chip, want_prune)
+                    t1 = time.perf_counter_ns()
+                ph.args = {"chip": c, "level": 1}
+                if r is None:
+                    ph.args["excluded"] = True
+                else:
+                    ph.args["skyline"] = int(r[2])
             if r is None:
                 # excluded this merge: the group contributes nothing and
                 # the answer publishes marked partial (RUNBOOK §2p)
@@ -506,13 +524,6 @@ class ShardedPartitionSet:
             chip_g.append(g_c)
             chip_pts.append(pts)
             chip_summary.append(summary)
-            if self._spans is not None:
-                # level-1 child span: /trace shows which chip's local
-                # tournament the merge wall went to
-                self._spans.record(
-                    "chip_merge", t0, t1, trace_id=trace_id, tid=c + 1,
-                    args={"chip": c, "level": 1, "skyline": int(g_c)},
-                )
             if self._fleet is not None:
                 self._fleet.note_level1(c, g_c, (t1 - t0) / 1e6)
             if self._health is not None:
@@ -546,106 +557,112 @@ class ShardedPartitionSet:
         concat_counts = np.concatenate(chip_counts)
         alive = np.array([g > 0 for g in chip_g], dtype=bool)
         considered = int(alive.sum())
-        # -- level 2: witness prune over chip summaries --------------------
-        pruned = np.zeros(C, dtype=bool)
-        witness_of = np.full(C, -1, dtype=np.int64)
-        if want_prune and considered > 1:
-            rows = [
-                chip_summary[c]
-                if chip_summary[c] is not None
-                else np.full(2 * d + 2, np.inf, dtype=np.float32)
-                for c in range(C)
-            ]
-            pruned, witness_of = prune_witness_mask(
-                np.stack(rows), alive, d
+        # level-2 phase: the witness prune over chip summaries, the gather
+        # onto the root device and the pairwise launches (kernels may still
+        # be in flight at harvest); its span in /trace is the interconnect
+        with self.tracer.span_phase(
+            "cross_chip/level2", self._spans, "cross_chip_merge",
+            trace_id=trace_id, tid=0,
+        ) as ph:
+            pruned = np.zeros(C, dtype=bool)
+            witness_of = np.full(C, -1, dtype=np.int64)
+            if want_prune and considered > 1:
+                rows = [
+                    chip_summary[c]
+                    if chip_summary[c] is not None
+                    else np.full(2 * d + 2, np.inf, dtype=np.float32)
+                    for c in range(C)
+                ]
+                pruned, witness_of = prune_witness_mask(
+                    np.stack(rows), alive, d
+                )
+            npruned = int(pruned.sum())
+            survivors = np.flatnonzero(alive & ~pruned)
+            self.sharded_merges += 1
+            self.chips_pruned_total += npruned
+            self.chips_considered_total += considered
+            CAPTURED.count("cross_chip/chips_considered", considered)
+            CAPTURED.count("cross_chip/chips_pruned", npruned)
+            # register the series at the first merge, not the first prune
+            self._inc("sharded.merges")
+            self._inc("sharded.chips_pruned", npruned)
+            self._fnote(
+                "sharded.merge", chips=C, alive=considered, pruned=npruned,
+                survivors=len(survivors),
             )
-        npruned = int(pruned.sum())
-        survivors = np.flatnonzero(alive & ~pruned)
-        self.sharded_merges += 1
-        self.chips_pruned_total += npruned
-        self.chips_considered_total += considered
-        # register the series at the first merge, not the first prune
-        self._inc("sharded.merges")
-        self._inc("sharded.chips_pruned", npruned)
-        self._fnote(
-            "sharded.merge", chips=C, alive=considered, pruned=npruned,
-            survivors=len(survivors),
-        )
-        if not len(survivors):
-            # every chip empty: the zero state needs no kernels
-            h.cached = True
-            h.result = (
-                concat_counts.astype(np.int64),
-                np.zeros(P, dtype=np.int64),
-                0,
-                np.empty((0, d), dtype=np.float32) if emit_points else None,
-            )
-            self._note_merge_info(
-                h, chip_g, considered, pruned, witness_of, survivors, 0, [0]
-            )
-            return h
-        # -- gather survivors onto the root device, ascending chip order ---
-        t2 = time.perf_counter_ns()
-        root_dev = self._devices[0]
-        leaves = []
-        for c in survivors:
-            g = chip_g[c]
-            w = chip_pts[c].shape[0]
-            if self._fleet is not None:
+            ph.args = {"level": 2, "survivors": len(survivors),
+                       "pruned": npruned, "levels": 0}
+            if not len(survivors):
+                # every chip empty: the zero state needs no kernels
+                h.cached = True
+                h.result = (
+                    concat_counts.astype(np.int64),
+                    np.zeros(P, dtype=np.int64),
+                    0,
+                    np.empty((0, d), dtype=np.float32) if emit_points else None,
+                )
+                self._note_merge_info(
+                    h, chip_g, considered, pruned, witness_of, survivors, 0,
+                    [0],
+                )
+                return h
+            # gather survivors onto the root device, ascending chip order
+            root_dev = self._devices[0]
+            leaves = []
+            shipped = 0
+            for c in survivors:
+                g = chip_g[c]
+                w = chip_pts[c].shape[0]
                 # the interconnect crossing: the padded root buffer ships
                 # to chip 0 — except chip 0's own root, already resident
-                self._fleet.note_level2(c, False, 0 if c == 0 else w)
-            vals = jax.device_put(chip_pts[c], root_dev)
-            # the chip root carries no pids; rebuild them host-side from
-            # the per-partition survivor counts (root rows are ascending
-            # local pid with per-partition storage order — the invariant
-            # byte identity rides on)
-            pid_np = np.zeros(w, dtype=np.int32)
-            pid_np[:g] = np.repeat(
-                np.arange(G, dtype=np.int32) + c * G,
-                chip_surv[c].astype(np.int64),
-            )
-            pids = jax.device_put(pid_np, root_dev)
-            cnt = jax.device_put(np.int32(g), root_dev)
-            leaves.append((vals, pids, cnt, g))
-        # -- pairwise tournament, adjacent pairs, odd tail passes through --
-        levels = 0
-        cand = [len(leaves)]
-        nodes = leaves
-        while len(nodes) > 1:
-            levels += 1
-            nxt = []
-            for i in range(0, len(nodes) - 1, 2):
-                av, ap, ac, aub = nodes[i]
-                bv, bp, bc, bub = nodes[i + 1]
-                out_cap = _active_bucket(max(aub + bub, 1))
-                vals, pids_out, cnt = tree_pair_merge(
-                    av, ap, ac, bv, bp, bc, out_cap
+                crossing = 0 if c == 0 else w
+                shipped += crossing
+                if self._fleet is not None:
+                    self._fleet.note_level2(c, False, crossing)
+                vals = jax.device_put(chip_pts[c], root_dev)
+                # the chip root carries no pids; rebuild them host-side
+                # from the per-partition survivor counts (root rows are
+                # ascending local pid with per-partition storage order —
+                # the invariant byte identity rides on)
+                pid_np = np.zeros(w, dtype=np.int32)
+                pid_np[:g] = np.repeat(
+                    np.arange(G, dtype=np.int32) + c * G,
+                    chip_surv[c].astype(np.int64),
                 )
-                nxt.append((vals, pids_out, cnt, min(aub + bub, out_cap)))
-            if len(nodes) % 2:
-                nxt.append(nodes[-1])
-            nodes = nxt
-            cand.append(len(nodes))
-        root_vals, root_pids, root_cnt, _ = nodes[0]
-        h.root_vals = root_vals
-        counts_dev = jax.device_put(
-            concat_counts.astype(np.int32), root_dev
-        )
-        h.stats = tree_stats_device(counts_dev, root_pids, root_cnt, P)
-        try:
-            h.stats.copy_to_host_async()
-        except AttributeError:
-            pass
-        if self._spans is not None:
-            # level-2 child span: survivor gather + cross-chip pairwise
-            # launches (kernels may still be in flight at harvest)
-            self._spans.record(
-                "cross_chip_merge", t2, time.perf_counter_ns(),
-                trace_id=trace_id, tid=0,
-                args={"level": 2, "survivors": len(survivors),
-                      "pruned": npruned, "levels": levels},
+                pids = jax.device_put(pid_np, root_dev)
+                cnt = jax.device_put(np.int32(g), root_dev)
+                leaves.append((vals, pids, cnt, g))
+            CAPTURED.count("cross_chip/rows_shipped", shipped)
+            # pairwise tournament, adjacent pairs, odd tail passes through
+            levels = 0
+            cand = [len(leaves)]
+            nodes = leaves
+            while len(nodes) > 1:
+                levels += 1
+                nxt = []
+                for i in range(0, len(nodes) - 1, 2):
+                    av, ap, ac, aub = nodes[i]
+                    bv, bp, bc, bub = nodes[i + 1]
+                    out_cap = _active_bucket(max(aub + bub, 1))
+                    vals, pids_out, cnt = tree_pair_merge(
+                        av, ap, ac, bv, bp, bc, out_cap
+                    )
+                    nxt.append((vals, pids_out, cnt, min(aub + bub, out_cap)))
+                if len(nodes) % 2:
+                    nxt.append(nodes[-1])
+                nodes = nxt
+                cand.append(len(nodes))
+            ph.args["levels"] = levels
+            root_vals, root_pids, root_cnt, _ = nodes[0]
+            h.root_vals = root_vals
+            counts_dev = jax.device_put(
+                concat_counts.astype(np.int32), root_dev
             )
+            h.stats = tree_stats_device(counts_dev, root_pids, root_cnt, P)
+            try:
+                h.stats.copy_to_host_async()
+            except AttributeError:
+                pass
         self._note_merge_info(
             h, chip_g, considered, pruned, witness_of, survivors, levels, cand
         )

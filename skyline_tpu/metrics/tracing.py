@@ -47,6 +47,9 @@ LAYERS: dict[str, str] = {
     "global_merge": "merge",
     "global_merge/": "merge",
     "query/": "merge",
+    # the sharded engine's two-level chip tournament (distributed/
+    # sharded.py): what its levels do beyond each chip's own merge phases
+    "cross_chip/": "cross_chip",
     "publish": "publish",
     "publish/": "publish",
     "audit/": "audit",

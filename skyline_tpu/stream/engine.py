@@ -81,6 +81,12 @@ class EngineConfig:
     # numpy routing in process_records; "device": force the device path
     # (errors if unsupported by the configuration)
     ingest: str = "auto"
+    # > 0: the sharded engine (``distributed/sharded.py``) splits the
+    # partitions into this many per-chip groups, chip c owning partitions
+    # [c*G, (c+1)*G), and answers by the two-level chip tournament; 0 runs
+    # every partition on one chip. Placement only: answers are
+    # byte-identical either way, and checkpoints restore onto any count
+    mesh_chips: int = 0
 
     @property
     def num_partitions(self) -> int:

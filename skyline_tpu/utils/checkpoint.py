@@ -156,9 +156,13 @@ def load_engine(
         # tolerate fields added/removed across versions within format 1
         import dataclasses
 
+        # the chip count is the restoring worker's, never the saved one:
+        # a checkpoint is placement-free (save_engine leaves it out)
         known = {f.name for f in dataclasses.fields(EngineConfig)}
+        known.discard("mesh_chips")
         cfg = EngineConfig(
-            **{k: v for k, v in meta["config"].items() if k in known}
+            **{k: v for k, v in meta["config"].items() if k in known},
+            mesh_chips=mesh_chips,
         )
         kw = {}
         if tracer is not None:

@@ -314,6 +314,7 @@ class JobConfig:
             flush_policy=self.flush_policy,
             overlap_rows=self.overlap_rows,
             ingest=self.ingest,
+            mesh_chips=self.mesh_chips,
         )
 
     def serve_config(self):

@@ -13,6 +13,7 @@ divergence.
 from __future__ import annotations
 
 import json
+from dataclasses import replace
 
 import numpy as np
 import pytest
@@ -263,11 +264,12 @@ def test_checkpoint_roundtrips_across_topologies(rng, tmp_path):
     flat = load_engine(path)
     assert isinstance(flat, SkylineEngine)
     assert not isinstance(flat, ShardedEngine)
+    assert flat.config.mesh_chips == 0
     assert_same_merge(base, merge_state(flat.pset), ctx="sharded->flat")
     # sharded checkpoint -> different chip count
     wide = load_engine(path, mesh_chips=4)
     assert isinstance(wide, ShardedEngine)
-    assert wide.mesh_chips == 4
+    assert wide.mesh_chips == wide.config.mesh_chips == 4
     assert_same_merge(base, merge_state(wide.pset), ctx="sharded->4chips")
     assert flat.records_in == wide.records_in == eng.records_in
 
@@ -391,8 +393,8 @@ def _sharded_worker(bus, tmp_path, d, chips, telem=None):
     return SkylineWorker(
         bus,
         EngineConfig(parallelism=2, dims=d, domain_max=10000.0,
-                     buffer_size=128, emit_skyline_points=True),
-        mesh_chips=chips,
+                     buffer_size=128, emit_skyline_points=True,
+                     mesh_chips=chips),
         resilience=res,
         telemetry=telem,
     )
@@ -532,8 +534,150 @@ def test_worker_rejects_mesh_chips_with_window():
     with pytest.raises(ValueError):
         SkylineWorker(
             MemoryBus(),
-            EngineConfig(parallelism=2, dims=2),
-            mesh_chips=2,
+            EngineConfig(parallelism=2, dims=2, mesh_chips=2),
             window_size=64,
             slide=32,
         )
+
+
+# --------------------------------------------------------------------------
+# the chip count as an engine config field
+# --------------------------------------------------------------------------
+
+
+def _answer_of(config, lines):
+    """A served worker's published answer to one immediate trigger."""
+    bus = MemoryBus()
+    worker = SkylineWorker(bus, config, serve_port=0)
+    try:
+        bus.produce_many("input-tuples", lines)
+        bus.produce("queries", format_trigger(1, 0))
+        while worker.step() > 0:
+            pass
+        out = [json.loads(m) for m in bus._topics["output-skyline"]]
+        assert [r["query_id"] for r in out] == ["1"]
+        pts = np.asarray(worker.engine.snapshots.latest().points)
+        return type(worker.engine), worker.engine.pset, out[0], pts
+    finally:
+        worker.close()
+
+
+def test_worker_runs_the_sharded_engine_from_the_config_field(monkeypatch):
+    """``EngineConfig(mesh_chips=4)`` at the four-chip cell's engine
+    settings: the worker builds the sharded engine on four devices and
+    publishes the flat engine's answer byte for byte, which is the plain
+    reference's skyline."""
+    from skybench import gen, reference
+
+    monkeypatch.setenv("SKYLINE_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("SKYLINE_SORTED_SFS", "off")
+    rows = gen.generate(
+        {"distribution": "anti_correlated", "dims": 8, "d_min": 0, "d_max": 10000},
+        gen.rng_for(2900000029, 0), 4000,
+    )
+    lines = gen.wire_lines(np.arange(rows.shape[0]), rows)
+    cell = dict(parallelism=4, algo="mr-angle", dims=8, domain_max=10000.0,
+                buffer_size=8192, initial_capacity=65536, flush_policy="lazy",
+                window_capacity=4000)
+    kind, pset, res, got = _answer_of(EngineConfig(**cell, mesh_chips=4), lines)
+    assert kind is ShardedEngine
+    assert pset.chips == 4 and len({d.id for d in pset._devices}) == 4
+    flat_kind, _, flat_res, want = _answer_of(EngineConfig(**cell), lines)
+    assert flat_kind is SkylineEngine
+    assert res["skyline_size"] == flat_res["skyline_size"] == got.shape[0]
+    assert got.tobytes() == want.tobytes()
+    assert reference.multiset_gap(got, reference.skyline(rows)) == 0
+
+
+def test_job_cli_mesh_chips_reaches_the_engine_config():
+    from skyline_tpu.utils.config import parse_job_args
+
+    cfg = parse_job_args(["--parallelism", "4", "--mesh-chips", "4"])
+    assert cfg.engine_config().mesh_chips == 4
+    assert parse_job_args(["--parallelism", "4"]).engine_config().mesh_chips == 0
+    clustered = parse_job_args(
+        ["--parallelism", "4", "--cluster-hosts", "2", "--mesh-chips", "2"]
+    )
+    # under --cluster-hosts the field is the chip count per host
+    assert clustered.engine_config().mesh_chips == 2
+
+
+@pytest.mark.parametrize("saved, running", [(2, 4), (2, 0), (0, 2)])
+def test_worker_restore_takes_its_own_chip_count(rng, tmp_path, saved, running):
+    """A checkpoint is placement-free: the restoring worker's
+    ``config.mesh_chips`` decides the engine, whatever the saving worker
+    ran."""
+    d = 4
+    rows = uniform(rng, 300, d, 0, 10000)
+    base = EngineConfig(parallelism=2, dims=d, domain_max=10000.0,
+                        buffer_size=128, emit_skyline_points=True)
+    res = ResilienceConfig(checkpoint_dir=str(tmp_path), wal_fsync="batch")
+    bus = MemoryBus()
+    _feed(bus, rows)
+    w = SkylineWorker(bus, replace(base, mesh_chips=saved), resilience=res)
+    try:
+        while w.step(max_records=64):
+            pass
+        assert w.checkpoint_now() is not None
+        want = w.engine.pset.global_merge_stats(emit_points=True)[3]
+    finally:
+        w.close()
+    w2 = SkylineWorker(MemoryBus(), replace(base, mesh_chips=running),
+                       resilience=res)
+    try:
+        assert w2._recovered is not None
+        assert w2.engine.config.mesh_chips == running
+        assert isinstance(w2.engine, ShardedEngine) == bool(running)
+        if running:
+            assert w2.engine.pset.chips == running
+        got = w2.engine.pset.global_merge_stats(emit_points=True)[3]
+        assert got.tobytes() == want.tobytes()
+    finally:
+        w2.close()
+
+
+def test_two_level_tournament_lands_on_the_capture_clock(rng, tmp_path):
+    """Under a profiler capture the tournament's phases count to the
+    ``cross_chip`` layer, the per-chip flush and the prune are counted,
+    and ``/trace`` keeps its ``chip_merge`` / ``cross_chip_merge`` spans."""
+    import jax
+
+    from skyline_tpu.metrics import tracing
+    from skyline_tpu.metrics.tracing import Tracer
+
+    d = 4
+    cfg = EngineConfig(parallelism=4, dims=d, buffer_size=64,
+                       domain_max=1.0, emit_skyline_points=True)
+    x = gen_points(rng, 800, d, "anti")
+    hub = Telemetry()
+    eng = ShardedEngine(cfg, chips=4, tracer=Tracer(), telemetry=hub)
+    tracing.CAPTURED.reset()
+    try:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+            (res,) = _run_engine(eng, x)
+        counts = tracing.CAPTURED.counts()
+        self_ms = tracing.CAPTURED.self_ms()
+    finally:
+        tracing.CAPTURED.reset()
+    assert self_ms["cross_chip"] > 0
+    assert counts["flush/chip_rows"] == x.shape[0]
+    assert x.shape[0] / 4 <= counts["flush/chip_rows_max"] <= x.shape[0]
+    info = eng.pset.last_chip_info
+    assert counts["cross_chip/chips_considered"] == info["alive"]
+    assert counts["cross_chip/chips_pruned"] == len(info["pruned"])
+    assert counts["cross_chip/rows_shipped"] >= 0
+    report = eng.tracer.report()
+    assert report["cross_chip/level1"]["count"] == 4
+    assert report["cross_chip/level2"]["count"] == 1
+    spans = hub.spans.snapshot()
+    level1 = sorted((s for s in spans if s["name"] == "chip_merge"), key=lambda s: s["tid"])
+    assert [s["tid"] for s in level1] == [1, 2, 3, 4]
+    assert [s["args"]["chip"] for s in level1] == [0, 1, 2, 3]
+    assert all(set(s["args"]) == {"chip", "level", "skyline"} for s in level1)
+    (level2,) = [s for s in spans if s["name"] == "cross_chip_merge"]
+    assert level2["tid"] == 0
+    assert set(level2["args"]) == {"level", "survivors", "pruned", "levels"}
+    assert level2["args"]["survivors"] == len(info["survivors"])
+    assert res["skyline_size"] > 0

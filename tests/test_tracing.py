@@ -110,7 +110,7 @@ def test_every_phase_the_program_opens_has_a_layer():
     assert not missing
     assert set(tracing.LAYERS.values()) == {
         "transport", "parse", "ingest", "flush", "merge", "publish", "audit",
-        "build", "worker",
+        "build", "worker", "cross_chip",
     }
 
 
@@ -130,6 +130,8 @@ def test_every_phase_the_program_opens_has_a_layer():
         ("publish", "publish"),
         ("publish/produce", "publish"),
         ("audit/state_sync", "audit"),
+        ("cross_chip/level1", "cross_chip"),
+        ("cross_chip/level2", "cross_chip"),
     ],
 )
 def test_layer_of(name, layer):
